@@ -612,8 +612,11 @@ def main() -> None:
                          "default), measured = the uniqueCount-sync escape "
                          "hatch, auto = planned for fused engines")
     ap.add_argument("--devices", type=int, default=1,
-                    help="shard the SpGEMM executor over N forced host "
-                         "devices (sets XLA_FLAGS before importing jax)")
+                    help="shard the SpGEMM executor over the first N "
+                         "devices of jax.devices(); on a CPU host this also "
+                         "forces N host devices (sets XLA_FLAGS before "
+                         "importing jax) — a TPU host's chips need no "
+                         "forcing")
     ap.add_argument("--json", default=None, metavar="PATH",
                     help="also write records as JSON (bench-smoke artifact)")
     ap.add_argument("--ci", action="store_true",
@@ -646,6 +649,9 @@ def main() -> None:
             os.environ.get("XLA_FLAGS", "")
             + f" --xla_force_host_platform_device_count={args.devices}"
         ).strip()
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     mesh = _make_mesh(args.devices)
 
     # --engine choices come from the live registry (not a frozen argparse

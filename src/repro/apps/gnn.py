@@ -135,8 +135,11 @@ def train_gnn(
     labels = jnp.asarray(labels)
     mask = jnp.ones(labels.shape[0], jnp.float32)
 
+    # The graph and features are arguments, not closed-over constants: a
+    # constant of this size would be folded into the program the compiler
+    # sees.
     @jax.jit
-    def step(params, opt_state):
+    def step(params, opt_state, a, x, labels, mask):
         loss, grads = jax.value_and_grad(
             lambda p: _loss_fn(cfg, p, a, x, labels, mask, mesh=mesh)
         )(params)
@@ -146,7 +149,7 @@ def train_gnn(
 
     history = []
     for _ in range(n_steps):
-        params, opt_state, loss = step(params, opt_state)
+        params, opt_state, loss = step(params, opt_state, a, x, labels, mask)
         history.append(float(loss))
     return params, history
 

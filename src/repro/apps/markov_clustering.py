@@ -9,8 +9,10 @@ paper benchmarks in Fig. 7/8.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import List
 
+import jax
 import numpy as np
 
 from repro.core import executor
@@ -47,12 +49,48 @@ def add_self_loops(g: CSR, weight: float = 1.0) -> CSR:
     return csr_from_coo(rows, cols, vals, g.shape)
 
 
+def _host_entries(a: CSR):
+    """(row-major flat keys, values) of ``a``'s stored entries, on the host."""
+    indptr = np.asarray(a.indptr).astype(np.int64)
+    nnz = int(indptr[-1])
+    rows = np.repeat(np.arange(a.n_rows, dtype=np.int64), np.diff(indptr))
+    keys = rows * a.n_cols + np.asarray(a.indices)[:nnz]
+    return keys, np.asarray(a.data)[:nnz]
+
+
 def _change(a: CSR, b: CSR) -> float:
-    """Frobenius distance between two same-structure-capacity CSRs (densified)."""
-    from repro.sparse.formats import csr_to_dense
-    da = np.asarray(csr_to_dense(a), np.float64)
-    db = np.asarray(csr_to_dense(b), np.float64)
-    return float(np.abs(da - db).max())
+    """Largest entrywise difference between two CSRs (of their dense forms,
+    computed from the stored entries on the host)."""
+    ka, va = _host_entries(a)
+    kb, vb = _host_entries(b)
+    keys, inv = np.unique(np.concatenate([ka, kb]), return_inverse=True)
+    diff = np.zeros(len(keys), np.float64)
+    np.add.at(diff, inv, np.concatenate([va.astype(np.float64),
+                                         -vb.astype(np.float64)]))
+    return float(np.abs(diff).max(initial=0.0))
+
+
+def drop_zeros(a: CSR) -> CSR:
+    """Remove the explicitly stored zeros — host-side structural edit.
+
+    ``csr_prune_columns`` zeroes pruned entries in place; dropping them is
+    what makes Prune shrink the next expansion (on a power-law graph the
+    retained structure of A² multiplied by itself runs to ~10⁹ intermediate
+    products).  The capacity is pow2-quantized, like the SpGEMM output's,
+    so iterations keep reusing compiled programs."""
+    keys, vals = _host_entries(a)
+    keep = vals != 0
+    keys, vals = keys[keep], vals[keep]
+    return csr_from_coo(keys // a.n_cols, keys % a.n_cols, vals, a.shape,
+                        capacity=executor.next_pow2(max(len(vals), 1)))
+
+
+@functools.partial(jax.jit, static_argnames=("k",))
+def _prune_inflate(b: CSR, theta, k: int, r) -> CSR:
+    """Prune (drop < theta, top-k per column), inflate (Hadamard power) and
+    column-normalize, as one compiled program."""
+    c = csr_prune_columns(b, theta, k)
+    return csr_column_normalize(csr_hadamard_power(c, r))
 
 
 def interpret_clusters(a: CSR) -> np.ndarray:
@@ -131,8 +169,7 @@ def mcl(
     method = executor.resolve_engine(method)
     stream = None if stream is None else executor.resolve_tile_rows(stream)
     on_budget = executor.resolve_on_budget(on_budget)
-    a = add_self_loops(g)
-    a = csr_column_normalize(a)
+    a = jax.jit(csr_column_normalize)(add_self_loops(g))
     plan_cache = PlanCache() if reuse_plan else None
     infos = []
     it = 0
@@ -154,11 +191,9 @@ def mcl(
                              on_budget=on_budget)
             infos.append(res.info)
             b = res.c
-        # Prune: drop < theta, keep top-k per column
-        c = csr_prune_columns(b, theta, k)
-        # Inflation: Hadamard power + column normalize
-        c = csr_hadamard_power(c, r)
-        a = csr_column_normalize(c)
+        # Prune (drop < theta, keep top-k per column), inflation (Hadamard
+        # power + column normalize), then compaction of the pruned entries
+        a = drop_zeros(_prune_inflate(b, theta, k, r))
         if a.shape == prev.shape and _change(a, prev) < tol:
             break
     clusters = interpret_clusters(a)

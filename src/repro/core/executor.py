@@ -17,7 +17,7 @@ Three pluggable axes, each resolved per group:
   live here instead of being duplicated in ``spgemm()``.
 * **gather** — how rows of B are fetched for the two-level indirection
   ``b_ell[cols_A]``.  ``"xla"`` is a plain ``jnp`` take; ``"aia"`` routes
-  through the scalar-prefetch Pallas kernels in ``kernels.aia_gather`` (the
+  through the SMEM-indexed DMA kernels in ``kernels.aia_gather`` (the
   paper's AIA ranged indirect access), auto-selecting compiled vs interpret
   mode from the JAX backend.  ``"auto"`` picks ``"aia"`` on TPU and
   ``"xla"`` elsewhere — the paper's software-only vs AIA ablation (Fig. 7)
@@ -147,6 +147,7 @@ different values.  Two mechanisms exploit that:
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import hashlib
 import os
@@ -443,22 +444,30 @@ def resolve_engine(engine: Optional[str] = None,
     return engine
 
 
-def static_bin_engines(backend: Optional[str] = None) -> Tuple[str, ...]:
+def static_bin_engines(table_capacities: Sequence[int],
+                       backend: Optional[str] = None) -> Tuple[str, ...]:
     """Static bin-size × backend seed for ``engine="auto"``.
 
-    The CI baseline says the vectorized sort engine dominates on CPU
-    (selfprod: sort 67 ms vs hash 500 ms / fused_hash 297 ms) while the
-    fused single-pass Pallas lane is the TPU winner, so the seed is
-    per-backend: every Table-I bin starts on ``"sort"`` off-TPU and on
-    ``"fused_hash"`` on TPU.  This is only the *starting point* — the
+    On CPU the CI baseline has the vectorized sort engine ahead (selfprod:
+    sort 67 ms vs hash 500 ms / fused_hash 297 ms, CPU wall times), so
+    every bin starts on ``"sort"`` off-TPU.  On TPU every bin starts on
+    ``"fused_hash"`` (the compiled Algorithm-4 kernel) — which engine wins
+    each bin on the chip is not measured.  One rule is structural: a bin
+    whose table (``table_capacities``, the plan's per-group capacities)
+    exceeds the kernel's SMEM budget (``kernels.hash_accum.table_fits``) starts
+    on ``"sort"`` instead.  This is only the *starting point* — the
     ``AutotuneCache`` measures each bin's candidates on the live pattern
     and converges to the measured per-bin optimum (nsparse-style adaptive
     accumulator selection, arXiv:1804.01698).
     """
     if backend is None:
         backend = jax.default_backend()
-    name = "fused_hash" if backend == "tpu" else "sort"
-    return (name, name, name, name)
+    if backend != "tpu":
+        return ("sort",) * 4
+    from repro.kernels.hash_accum import table_fits
+
+    return tuple("fused_hash" if table_fits(c) else "sort"
+                 for c in table_capacities)
 
 
 def _hash_accumulate(keys, vals, table_cap: int, out_cap: int):
@@ -507,38 +516,53 @@ def resolve_gather(gather: Gather) -> str:
     return gather
 
 
-def _gather_b_xla(b_idx, b_val, cols_a):
+def ell_width(kb_cap: int, gather: str) -> int:
+    """Stored width of B's placed ELL planes.
+
+    The AIA row DMA moves whole 128-lane tiles, so the ``aia`` path places
+    B pre-padded to the tile (once per placed operand, in the
+    ``OperandCache``) and its programs trim gathered rows back to
+    ``kb_cap``; the ``xla`` take reads the exact width."""
+    if gather == "aia":
+        from repro.kernels.aia_gather import padded_width
+
+        return padded_width(kb_cap)
+    return kb_cap
+
+
+def _gather_b_xla(b_idx, b_val, cols_a, kb):
     safe = jnp.clip(cols_a, 0, b_idx.shape[0] - 1)
-    return b_idx[safe], b_val[safe]
+    return b_idx[safe][..., :kb], b_val[safe][..., :kb]
 
 
-def _gather_b_aia(b_idx, b_val, cols_a):
-    """B-row gather as the paper's AIA stream (scalar-prefetch DMA kernel).
+def _gather_b_aia(b_idx, b_val, cols_a, kb):
+    """B-row gather as the paper's AIA stream (SMEM-indexed DMA kernel).
 
     ``cols_a`` rows are flattened into one bulk index stream, gathered
-    near-memory, and reshaped back; the interpret/compiled choice follows
-    the JAX backend inside the kernel.
+    near-memory from the lane-padded planes, reshaped back and trimmed to
+    the exact ``kb`` (padding columns hold -1/0, so the trim is exact).
     """
     from repro.kernels.aia_gather import gather_rows_any
 
     r, a_cap = cols_a.shape
-    kb = b_idx.shape[1]
+    width = b_idx.shape[1]
     flat = cols_a.reshape(-1)
-    bi = gather_rows_any(b_idx, flat)
-    bv = gather_rows_any(b_val, flat)
-    return bi.reshape(r, a_cap, kb), bv.reshape(r, a_cap, kb)
+    bi = gather_rows_any(b_idx, flat).reshape(r, a_cap, width)
+    bv = gather_rows_any(b_val, flat).reshape(r, a_cap, width)
+    return bi[..., :kb], bv[..., :kb]
 
 
 GATHERS: Dict[str, Callable] = {"xla": _gather_b_xla, "aia": _gather_b_aia}
 
 
-def _gather_b_xla_batched(b_idx, b_val_b, cols_a):
+def _gather_b_xla_batched(b_idx, b_val_b, cols_a, kb):
     """Batched-value variant: one structural gather, values broadcast."""
     safe = jnp.clip(cols_a, 0, b_idx.shape[0] - 1)
-    return b_idx[safe], b_val_b[:, safe]  # (R,a_cap,kb), (B,R,a_cap,kb)
+    # (R, a_cap, kb), (B, R, a_cap, kb)
+    return b_idx[safe][..., :kb], b_val_b[:, safe][..., :kb]
 
 
-def _gather_b_aia_batched(b_idx, b_val_b, cols_a):
+def _gather_b_aia_batched(b_idx, b_val_b, cols_a, kb):
     """Batched AIA gather: the batch axis folds into the row payload, so a
     single widened DMA stream serves every batch member's B rows — the same
     index stream, amortized (the near-memory analogue of reading one wider
@@ -546,13 +570,13 @@ def _gather_b_aia_batched(b_idx, b_val_b, cols_a):
     from repro.kernels.aia_gather import gather_rows_any
 
     r, a_cap = cols_a.shape
-    nb, kb = b_idx.shape
+    nb, width = b_idx.shape
     batch = b_val_b.shape[0]
     flat = cols_a.reshape(-1)
-    bi = gather_rows_any(b_idx, flat).reshape(r, a_cap, kb)
-    folded = jnp.transpose(b_val_b, (1, 0, 2)).reshape(nb, batch * kb)
-    bv = gather_rows_any(folded, flat).reshape(r, a_cap, batch, kb)
-    return bi, jnp.transpose(bv, (2, 0, 1, 3))
+    bi = gather_rows_any(b_idx, flat).reshape(r, a_cap, width)
+    folded = jnp.transpose(b_val_b, (1, 0, 2)).reshape(nb, batch * width)
+    bv = gather_rows_any(folded, flat).reshape(r, a_cap, batch, width)
+    return bi[..., :kb], jnp.transpose(bv, (2, 0, 1, 3))[..., :kb]
 
 
 BATCHED_GATHERS: Dict[str, Callable] = {
@@ -636,14 +660,17 @@ def _planned_out_cap(max_unique: int, table_cap: int, ncol_cap: int) -> int:
                    ncol_cap), 1)
 
 
-def _fused_kernel_mode(dt: str) -> str:
+def _fused_kernel_mode(dt: str, table_cap: int) -> str:
     """Algorithm-4 routing inside the fused program: the Pallas kernel
     (compiled on TPU, interpret under ``REPRO_KERNEL_BACKEND=interpret``)
-    for float32 streams, the vmapped scan engine everywhere else (the
-    kernel's value plane is float32-only)."""
-    if dt != np.dtype(np.float32).str:
+    for float32 streams whose table fits the kernel's SMEM budget
+    (``hash_accum.table_fits``), the vmapped scan engine everywhere else
+    (the kernel's value plane is float32-only)."""
+    from repro.kernels.hash_accum import table_fits
+
+    if dt != np.dtype(np.float32).str or not table_fits(table_cap):
         return "xla"
-    from repro.kernels.ops import resolve_backend
+    from repro.kernels.backend import resolve_backend
 
     be = resolve_backend("auto")
     return be if be in ("pallas", "interpret") else "xla"
@@ -1077,7 +1104,7 @@ class AutotuneCache:
     def _entry_for(self, key: tuple, plan: GroupPlan) -> _AutotuneEntry:
         entry = self._entries.get(key)
         if entry is None:
-            seed = static_bin_engines()
+            seed = static_bin_engines(plan.table_capacities)
             entry = _AutotuneEntry(
                 seed=seed,
                 pending={g: self._candidate_order(seed[g])
@@ -1246,7 +1273,7 @@ def _autotune_assignment(a, b, plan, gather, row_chunk, mesh, pipeline,
     return cache.assignment_for(autotune_key(a, b, plan), plan, measure)
 
 
-def _build_enumerate(a_cap: int, gather: str,
+def _build_enumerate(a_cap: int, kb_cap: int, gather: str,
                      remapped: bool = False) -> Callable:
     """Compile the product-enumeration program: A-row gather → B-row gather
     (xla or AIA stream) → intermediate products.  Output stays on device and
@@ -1267,7 +1294,7 @@ def _build_enumerate(a_cap: int, gather: str,
         )
         if remapped:
             cols_a = phases.remap_columns(cols_a, remap)
-        bi, bv = gat(b_idx, b_val, cols_a)
+        bi, bv = gat(b_idx, b_val, cols_a, kb_cap)
         return phases.combine_products(cols_a, vals_a, bi, bv)
 
     return program
@@ -1284,7 +1311,7 @@ def _build_accumulate(table_cap: int, out_cap: int, engine: str) -> Callable:
         lambda keys, vals: eng.accumulate(keys, vals, table_cap, out_cap))
 
 
-def _build_enumerate_batched(a_cap: int, gather: str,
+def _build_enumerate_batched(a_cap: int, kb_cap: int, gather: str,
                              remapped: bool = False) -> Callable:
     """Batched enumerate: structure (keys) computed once, value streams
     carry the leading batch axis.  Shares the allocation program with the
@@ -1300,7 +1327,7 @@ def _build_enumerate_batched(a_cap: int, gather: str,
         )
         if remapped:
             cols_a = phases.remap_columns(cols_a, remap)
-        bi, bv_b = gat(b_idx, b_val_b, cols_a)
+        bi, bv_b = gat(b_idx, b_val_b, cols_a, kb_cap)
         return phases.combine_products_batched(cols_a, vals_a_b, bi, bv_b)
 
     return program
@@ -1308,16 +1335,24 @@ def _build_enumerate_batched(a_cap: int, gather: str,
 
 def _build_accumulate_batched(table_cap: int, out_cap: int,
                               engine: str) -> Callable:
-    """vmap the engine's accumulate over the batch's value sets (keys are
-    shared, so every member produces the same cols/counts — the caller
-    reads them from member 0)."""
+    """vmap the engine's accumulate over the batch's value sets.  Keys are
+    shared, so every member produces the same cols/counts: the program
+    returns member 0's (cols (R, out_cap), vals (B, R, out_cap), counts
+    (R,))."""
     eng = get_engine(engine)
-    return jax.jit(lambda keys, vals_b: jax.vmap(
-        lambda v: eng.accumulate(keys, v, table_cap, out_cap))(vals_b))
+
+    @jax.jit
+    def program(keys, vals_b):
+        cols, vals, counts = jax.vmap(
+            lambda v: eng.accumulate(keys, v, table_cap, out_cap))(vals_b)
+        return cols[0], vals, counts[0]
+
+    return program
 
 
-def _build_fused(a_cap: int, gather: str, table_cap: int, out_cap: int,
-                 kernel: str, remapped: bool = False) -> Callable:
+def _build_fused(a_cap: int, kb_cap: int, gather: str, table_cap: int,
+                 out_cap: int, kernel: str, remapped: bool = False
+                 ) -> Callable:
     """Compile the fused single-pass program: A-row gather → B-row gather
     (xla or the AIA stream, feeding the table directly) → product
     formation → linear-probe insertion → sorted trim, all one jitted
@@ -1333,7 +1368,7 @@ def _build_fused(a_cap: int, gather: str, table_cap: int, out_cap: int,
         )
         if remapped:
             cols_a = phases.remap_columns(cols_a, remap)
-        bi, bv = gat(b_idx, b_val, cols_a)
+        bi, bv = gat(b_idx, b_val, cols_a, kb_cap)
         keys, vals = phases.combine_products(cols_a, vals_a, bi, bv)
         return phases.fused_hash_sorted(keys, vals, table_cap, out_cap,
                                         kernel=kernel)
@@ -1341,12 +1376,14 @@ def _build_fused(a_cap: int, gather: str, table_cap: int, out_cap: int,
     return program
 
 
-def _build_fused_batched(a_cap: int, gather: str, table_cap: int,
-                         out_cap: int, remapped: bool = False) -> Callable:
+def _build_fused_batched(a_cap: int, kb_cap: int, gather: str,
+                         table_cap: int, out_cap: int,
+                         remapped: bool = False) -> Callable:
     """Batched fused program: the structural gather and key stream run
     once, the per-member value streams are vmapped through the single-pass
     insert (scan engine — the batch axis rides XLA's vmap, not the Pallas
-    grid)."""
+    grid).  Returns member 0's cols/counts (shared structure) with every
+    member's values, like the batched accumulate."""
     gat = BATCHED_GATHERS[gather]
 
     @jax.jit
@@ -1357,11 +1394,12 @@ def _build_fused_batched(a_cap: int, gather: str, table_cap: int,
         )
         if remapped:
             cols_a = phases.remap_columns(cols_a, remap)
-        bi, bv_b = gat(b_idx, b_val_b, cols_a)
+        bi, bv_b = gat(b_idx, b_val_b, cols_a, kb_cap)
         keys, vals_b = phases.combine_products_batched(
             cols_a, vals_a_b, bi, bv_b)
-        return jax.vmap(lambda v: phases.fused_hash_sorted(
+        cols, vals, counts = jax.vmap(lambda v: phases.fused_hash_sorted(
             keys, v, table_cap, out_cap, kernel="xla"))(vals_b)
+        return cols[0], vals, counts[0]
 
     return program
 
@@ -1431,6 +1469,43 @@ def _get_program(kind: str, key: tuple, *build_args) -> Callable:
     return prog
 
 
+@contextlib.contextmanager
+def record_lowerings(kinds: Sequence[str] = ("fused", "enumerate")):
+    """Record the StableHLO text of each program of ``kinds`` built inside
+    the block, lowered from the arguments of its first call.
+
+    Yields ``{kind: [text, ...]}``; lets a caller check what a program
+    compiled to (for example that it holds a ``tpu_custom_call`` kernel).
+    The program cache is cleared on entry and exit, so every program the
+    block runs is built (and recorded) here and no recording wrapper
+    outlives it."""
+    texts = {k: [] for k in kinds}
+    originals = {k: _BUILDERS[k] for k in kinds}
+
+    def wrap(kind, build):
+        def builder(*build_args):
+            prog = build(*build_args)
+            seen = []
+
+            def run(*args):
+                if not seen:
+                    seen.append(True)
+                    texts[kind].append(prog.lower(*args).as_text())
+                return prog(*args)
+
+            return run
+
+        return builder
+
+    clear_program_cache()
+    _BUILDERS.update({k: wrap(k, b) for k, b in originals.items()})
+    try:
+        yield texts
+    finally:
+        _BUILDERS.update(originals)
+        clear_program_cache()
+
+
 # ---------------------------------------------------------------------------
 # Plan execution
 # ---------------------------------------------------------------------------
@@ -1466,6 +1541,7 @@ class WorkItem:
     rows: np.ndarray      # (R,) original row ids of this chunk
     a_cap: int            # exact max nnz(A row) over the *group*
     table_cap: int        # Table-I hash-table capacity of the group
+    padded: int           # rows the chunk's programs take (padding: -1 ids)
     engine: Optional[str] = None  # per-bin engine (None = caller's engine=)
 
 
@@ -1483,6 +1559,8 @@ def partition_plan(
     never ends up holding only the heavy group-3 rows).  With multiple
     shards the chunk size shrinks to ``ceil(group/n_shards)`` (quantized to
     ``ROW_QUANTUM``) so every shard gets work from every group it can.
+    Every chunk of a group is padded to the group's first chunk
+    (``WorkItem.padded``), so the chunks share their compiled programs.
 
     ``a_cap`` stays a *group-level* maximum: per-row results then never
     depend on the chunking or the shard count, which is what makes the
@@ -1504,6 +1582,9 @@ def partition_plan(
         if n_shards > 1:
             per_shard = _pad_rows(int(np.ceil(len(rows) / n_shards)))
             chunk = max(min(row_chunk, per_shard), ROW_QUANTUM)
+        # Every chunk of the group pads to one length, so they share one
+        # compiled program per phase.
+        padded = _pad_rows(min(chunk, len(rows)))
         for lo in range(0, len(rows), chunk):
             items.append(WorkItem(
                 group=g,
@@ -1512,6 +1593,7 @@ def partition_plan(
                 a_cap=a_cap,
                 table_cap=table_cap,
                 engine=None if group_engines is None else group_engines[g],
+                padded=padded,
             ))
             cursor += 1
     return items
@@ -1665,10 +1747,10 @@ def _setup_execution(a: CSR, b: CSR, plan: GroupPlan, engine: str,
     return gather, kb_cap, ncol_cap, devices, items, footprints
 
 
-def _chunk_rows_padded(chunk: np.ndarray, dev):
-    """Pad a chunk's row ids to the quantized length (-1 = padding row)
-    and place them on the item's shard device."""
-    padded = _pad_rows(len(chunk))
+def _chunk_rows_padded(item: WorkItem, dev):
+    """Pad a chunk's row ids to the item's padded length (-1 = padding
+    row) and place them on the item's shard device."""
+    chunk, padded = item.rows, item.padded
     rows_j = replicate_to(jnp.asarray(np.concatenate(
         [chunk, -np.ones(padded - len(chunk), np.int32)]
     )), dev)
@@ -2019,12 +2101,14 @@ def execute_plan(
     ocache = operand_cache if operand_cache is not None else _OPERAND_CACHE
     try:
         faults.fire("gather_fail")
-        b_entry = ocache.b_operands(b, kb_cap, devices, footprints=footprints)
+        b_entry = ocache.b_operands(b, ell_width(kb_cap, gather), devices,
+                                   footprints=footprints)
     except faults.FaultInjected:
         # Transient placement failure: B-operand gather/placement is
         # idempotent (pure function of B + devices), so one re-issue is the
         # whole recovery (docs/resilience.md).
-        b_entry = ocache.b_operands(b, kb_cap, devices, footprints=footprints)
+        b_entry = ocache.b_operands(b, ell_width(kb_cap, gather), devices,
+                                   footprints=footprints)
     a_ops = _shard_a_operands((a.indptr, a.indices, a.data), devices)
     shape = (a.n_rows, b.n_cols)
     if pipeline == "legacy":
@@ -2052,10 +2136,10 @@ def execute_plan(
         a_ip, a_ix, a_dt = a_ops[item.shard]
         b_ix, b_vl, b_rm = b_entry.shards[item.shard]
         rmk = b_rm is not None
-        padded, rows_j = _chunk_rows_padded(item.rows, dev)
+        padded, rows_j = _chunk_rows_padded(item, dev)
         enum = _get_program(
             "enumerate", (padded, item.a_cap, kb_cap, gather, dt, rmk),
-            item.a_cap, gather, rmk)
+            item.a_cap, kb_cap, gather, rmk)
         keys, vals = enum(a_ip, a_ix, a_dt, rows_j, b_ix, b_vl, b_rm)
         pend.append((item, padded, keys, vals,
                      _alloc_counts(keys, padded, item.table_cap,
@@ -2107,7 +2191,6 @@ def _run_planned(items, devices, a_ops, b_ops, plan, n, dtype, dt, kb_cap,
     (``engine="auto"``) dispatch their own engine's programs; the sizing
     rule guarantees every engine reaching this sync-free core is fused.
     """
-    kernel = _fused_kernel_mode(dt)
     bounds = [chunk_capacity_bounds(plan, item.rows, ncol) for item in items]
     cap = _int32_nnz_capacity(sum(s for _, s in bounds))
     bkey = () if batch is None else (batch,)
@@ -2119,7 +2202,7 @@ def _run_planned(items, devices, a_ops, b_ops, plan, n, dtype, dt, kb_cap,
         a_arrs = a_ops[item.shard]
         b_ix, b_vl, b_rm = b_ops[item.shard]
         rmk = b_rm is not None
-        padded, rows_j = _chunk_rows_padded(item.rows, dev)
+        padded, rows_j = _chunk_rows_padded(item, dev)
         out_cap = _planned_out_cap(max_u, item.table_cap, ncol_cap)
         if faults.trigger("capacity_undersize"):
             # Chaos hook (docs/resilience.md): shrink this chunk's planned
@@ -2128,23 +2211,25 @@ def _run_planned(items, devices, a_ops, b_ops, plan, n, dtype, dt, kb_cap,
             out_cap = 1
         if eng.fused:
             if batch is None:
+                kernel = _fused_kernel_mode(dt, item.table_cap)
                 prog = _get_program(
                     "fused",
                     (padded, item.a_cap, kb_cap, item.table_cap, out_cap,
                      gather, dt, kernel, rmk),
-                    item.a_cap, gather, item.table_cap, out_cap, kernel, rmk)
+                    item.a_cap, kb_cap, gather, item.table_cap, out_cap, kernel,
+                    rmk)
             else:
                 prog = _get_program(
                     "bfused",
                     (batch, padded, item.a_cap, kb_cap, item.table_cap,
                      out_cap, gather, dt, rmk),
-                    item.a_cap, gather, item.table_cap, out_cap, rmk)
+                    item.a_cap, kb_cap, gather, item.table_cap, out_cap, rmk)
             cols_r, vals_r, counts_r = prog(*a_arrs, rows_j, b_ix, b_vl, b_rm)
         else:
             enum = _get_program(
                 "enumerate" if batch is None else "benumerate",
                 bkey + (padded, item.a_cap, kb_cap, gather, dt, rmk),
-                item.a_cap, gather, rmk)
+                item.a_cap, kb_cap, gather, rmk)
             keys, vals = enum(*a_arrs, rows_j, b_ix, b_vl, b_rm)
             accum = _get_program(
                 "accumulate" if batch is None else "baccumulate",
@@ -2152,8 +2237,6 @@ def _run_planned(items, devices, a_ops, b_ops, plan, n, dtype, dt, kb_cap,
                         eng_name, dt),
                 item.table_cap, out_cap, eng_name)
             cols_r, vals_r, counts_r = accum(keys, vals)
-        if batch is not None:  # shared structure: member 0 carries it
-            cols_r, counts_r = cols_r[0], counts_r[0]
         runs.append(_ChunkRun(item, padded, out_cap, cols_r, vals_r,
                               counts_r))
 
@@ -2218,10 +2301,10 @@ def _execute_plan_legacy(items, devices, a_ops, b_entry, n, shape, dtype, dt,
         b_ix, b_vl, b_rm = b_entry.shards[item.shard]
         rmk = b_rm is not None
         a_cap, table_cap = item.a_cap, item.table_cap
-        padded, rows_j = _chunk_rows_padded(chunk, dev)
+        padded, rows_j = _chunk_rows_padded(item, dev)
         enum = _get_program(
             "enumerate", (padded, a_cap, kb_cap, gather, dt, rmk),
-            a_cap, gather, rmk)
+            a_cap, kb_cap, gather, rmk)
         keys, vals = enum(a_ip, a_ix, a_dt, rows_j, b_ix, b_vl, b_rm)
         ip_cap = keys.shape[1]
         eng_name = item.engine or engine
@@ -2274,7 +2357,7 @@ class _BatchChunkOut:
     counts: np.ndarray    # (R_pad,)
 
 
-def _batched_operands(a: CSR, b: CSR, a_data_batch, b_data_batch, kb_cap: int,
+def _batched_operands(a: CSR, b: CSR, a_data_batch, b_data_batch, width: int,
                       devices, footprints=None, operand_cache=None):
     """Per-shard batched operand placement.  The B-side structural buffers
     (ELL indices + the shared value plane) come from the ``OperandCache``
@@ -2287,7 +2370,7 @@ def _batched_operands(a: CSR, b: CSR, a_data_batch, b_data_batch, kb_cap: int,
             f"a_data_batch must be (batch, capacity), got {a_data_batch.shape}")
     batch = a_data_batch.shape[0]
     ocache = operand_cache if operand_cache is not None else _OPERAND_CACHE
-    b_entry = ocache.b_operands(b, kb_cap, devices, footprints=footprints)
+    b_entry = ocache.b_operands(b, width, devices, footprints=footprints)
     if b_data_batch is None:
         # shared B values: broadcast each shard's cached placement in place
         # (a broadcast of a device-resident array stays on that device)
@@ -2302,9 +2385,9 @@ def _batched_operands(a: CSR, b: CSR, a_data_batch, b_data_batch, kb_cap: int,
             raise ValueError(
                 f"batch mismatch: {batch} A value sets vs "
                 f"{b_data_batch.shape[0]} B value sets")
-        # structure-only scatter into ELL layout, vmapped over value sets
-        to_ell_data = jax.vmap(lambda d: csr_to_ell(
-            CSR(b.indptr, b.indices, d, b.shape), kb_cap).data)
+        # structure-only ELL layout, vmapped over value sets
+        to_ell_data = jax.jit(jax.vmap(lambda d: csr_to_ell(
+            CSR(b.indptr, b.indices, d, b.shape), width).data))
         b_val_b = to_ell_data(jnp.asarray(b_data_batch))
         entry_fps = b_entry.footprints or [None] * len(devices)
         b_shards = []
@@ -2391,7 +2474,7 @@ def execute_plan_batched(
         group_engines=group_engines, operands=operands)
     n = a.n_rows
     a_data_batch, batch, a_shards, b_shards = _batched_operands(
-        a, b, a_data_batch, b_data_batch, kb_cap, devices,
+        a, b, a_data_batch, b_data_batch, ell_width(kb_cap, gather), devices,
         footprints=footprints, operand_cache=operand_cache)
     dtype = a_data_batch.dtype
     dt = np.dtype(dtype).str
@@ -2416,11 +2499,11 @@ def execute_plan_batched(
         a_ip, a_ix, a_db = a_shards[item.shard]
         b_ix, b_vb, b_rm = b_shards[item.shard]
         rmk = b_rm is not None
-        padded, rows_j = _chunk_rows_padded(item.rows, dev)
+        padded, rows_j = _chunk_rows_padded(item, dev)
         benum = _get_program(
             "benumerate",
             (batch, padded, item.a_cap, kb_cap, gather, dt, rmk),
-            item.a_cap, gather, rmk)
+            item.a_cap, kb_cap, gather, rmk)
         keys, vals_b = benum(a_ip, a_ix, a_db, rows_j, b_ix, b_vb, b_rm)
         pend.append((item, padded, keys, vals_b,
                      _alloc_counts(keys, padded, item.table_cap,
@@ -2450,8 +2533,7 @@ def execute_plan_batched(
         cols_rb, vals_rb, counts_rb = bacc(keys, vals_b)
         starts_dev = devices[item.shard] if epi.sharded else epi.merge_dev
         epi.add_chunk(
-            _ChunkRun(item, padded, out_cap, cols_rb[0], vals_rb,
-                      counts_rb[0]),
+            _ChunkRun(item, padded, out_cap, cols_rb, vals_rb, counts_rb),
             _chunk_starts(indptr, item.rows, padded, starts_dev))
     idx_buf, dat_buf_b = epi.finish()
 
@@ -2474,10 +2556,10 @@ def _execute_plan_batched_legacy(items, devices, a_shards, b_shards, n,
         b_ix, b_vb, b_rm = b_shards[item.shard]
         rmk = b_rm is not None
         a_cap, table_cap = item.a_cap, item.table_cap
-        padded, rows_j = _chunk_rows_padded(chunk, dev)
+        padded, rows_j = _chunk_rows_padded(item, dev)
         benum = _get_program(
             "benumerate", (batch, padded, a_cap, kb_cap, gather, dt, rmk),
-            a_cap, gather, rmk)
+            a_cap, kb_cap, gather, rmk)
         keys, vals_b = benum(a_ip, a_ix, a_db, rows_j, b_ix, b_vb, b_rm)
         ip_cap = keys.shape[1]
         eng_name = item.engine or engine
@@ -2490,9 +2572,9 @@ def _execute_plan_batched_legacy(items, devices, a_shards, b_shards, n,
         cols_rb, vals_rb, counts_rb = bacc(keys, vals_b)
         out = _BatchChunkOut(
             rows=np.asarray(chunk),
-            cols=np.asarray(cols_rb[0]),
+            cols=np.asarray(cols_rb),
             vals=np.asarray(vals_rb),
-            counts=np.asarray(counts_rb[0]),
+            counts=np.asarray(counts_rb),
         )
         counts_all[out.rows] = out.counts[: len(chunk)]
         chunks.append(out)

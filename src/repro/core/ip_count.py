@@ -16,6 +16,7 @@ import jax.numpy as jnp
 from repro.sparse.formats import CSR
 
 
+@jax.jit
 def intermediate_products(a: CSR, b: CSR) -> jax.Array:
     """IP per row of A (int32, shape (a.n_rows,)). Algorithm 1, vectorized."""
     row_nnz_b = b.row_nnz()  # (= rpt_B[col+1] - rpt_B[col] precomputed)

@@ -30,6 +30,28 @@ INT_MAX = jnp.int32(2**31 - 1)
 # Intermediate-product enumeration (the two-level indirection itself)
 # ---------------------------------------------------------------------------
 
+def stream_width(ip_cap: int) -> int:
+    """Width of a padded intermediate-product stream of ``ip_cap`` products:
+    the next power of two up to 1024, a multiple of 1024 beyond.
+
+    The TPU compiler takes tens of seconds over the accumulate programs'
+    scatters and sorts at widths such as 2955 (``a_cap·kb_cap`` of a
+    power-law B) and about a second at these widths; the padding slots are
+    ``-1`` keys, which every engine skips, so results do not change."""
+    ip_cap = max(int(ip_cap), 1)
+    if ip_cap <= 1024:
+        return 1 << (ip_cap - 1).bit_length()
+    return -(-ip_cap // 1024) * 1024
+
+
+def _pad_stream(x, width: int, fill):
+    pad = width - x.shape[-1]
+    if pad == 0:
+        return x
+    return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, pad)],
+                   constant_values=fill)
+
+
 def combine_products(cols_a, vals_a, bi, bv):
     """Form intermediate products from already-gathered B rows.
 
@@ -37,14 +59,16 @@ def combine_products(cols_a, vals_a, bi, bv):
     bi, bv:         (R, a_cap, kb) the gathered B rows ``b_idx[cols_a]`` /
                     ``b_val[cols_a]`` (any gather backend; padding rows may
                     hold garbage — they are masked by ``cols_a < 0``).
-    Returns keys (R, a_cap*kb) int32 (-1 padded) and vals (same shape).
+    Returns keys (R, stream_width(a_cap*kb)) int32 (-1 padded) and vals
+    (same shape).
     """
     r, a_cap = cols_a.shape
     kb = bi.shape[2]
+    w = stream_width(a_cap * kb)
     valid = (cols_a >= 0)[:, :, None] & (bi >= 0)
     keys = jnp.where(valid, bi, -1).reshape(r, a_cap * kb)
     vals = jnp.where(valid, vals_a[:, :, None] * bv, 0).reshape(r, a_cap * kb)
-    return keys, vals
+    return _pad_stream(keys, w, -1), _pad_stream(vals, w, 0)
 
 
 def combine_products_batched(cols_a, vals_a_b, bi, bv_b):
@@ -56,15 +80,17 @@ def combine_products_batched(cols_a, vals_a_b, bi, bv_b):
 
     cols_a: (R, a_cap) shared structure; vals_a_b: (B, R, a_cap).
     bi: (R, a_cap, kb) shared gathered B structure; bv_b: (B, R, a_cap, kb).
-    Returns keys (R, a_cap*kb) and vals (B, R, a_cap*kb).
+    Returns keys (R, W) and vals (B, R, W), W = stream_width(a_cap*kb).
     """
     r, a_cap = cols_a.shape
     kb = bi.shape[2]
     batch = vals_a_b.shape[0]
+    w = stream_width(a_cap * kb)
     valid = (cols_a >= 0)[:, :, None] & (bi >= 0)
     keys = jnp.where(valid, bi, -1).reshape(r, a_cap * kb)
     vals = jnp.where(valid[None], vals_a_b[:, :, :, None] * bv_b, 0)
-    return keys, vals.reshape(batch, r, a_cap * kb)
+    return (_pad_stream(keys, w, -1),
+            _pad_stream(vals.reshape(batch, r, a_cap * kb), w, 0))
 
 
 def enumerate_products(cols_a, vals_a, b_idx, b_val):
@@ -72,11 +98,12 @@ def enumerate_products(cols_a, vals_a, b_idx, b_val):
 
     cols_a, vals_a: (R, a_cap) padded with -1 / 0 — the rows' A entries.
     b_idx, b_val:  (nB, kb_cap) ELL of B.
-    Returns keys (R, a_cap*kb_cap) int32 (-1 padded) and vals (same shape).
+    Returns keys (R, stream_width(a_cap*kb_cap)) int32 (-1 padded) and
+    vals (same shape).
 
     ``b_idx[cols_a]`` is exactly the AIA ranged indirect access
     (``rpt_B[col_A[j]]`` → row of B); here expressed as an XLA gather, in
-    ``repro.kernels.aia_gather`` as a scalar-prefetch DMA stream (selected
+    ``repro.kernels.aia_gather`` as an SMEM-indexed DMA stream (selected
     via the executor's ``gather=`` knob).
     """
     safe = jnp.clip(cols_a, 0, b_idx.shape[0] - 1)
@@ -166,6 +193,35 @@ def fused_hash_sorted(keys, vals, table_cap: int, out_cap: int,
 # Device-side CSR reassembly epilogue (inverse-permutation scatter on device)
 # ---------------------------------------------------------------------------
 
+# Largest scatter operand (elements) the epilogue writes in one piece.  The
+# TPU compiler spends seconds on a scatter into a buffer of 2**22 or more
+# elements and a fraction of one below that, so larger buffers are
+# scattered piece by piece and joined.
+SCATTER_PIECE = 1 << 21
+
+
+def scatter_drop(buf, pos, upd):
+    """``buf.at[..., pos].set(upd, mode="drop")`` along the last axis.
+
+    ``pos`` must be non-negative; positions at or past the buffer's length
+    are dropped.  A buffer larger than ``SCATTER_PIECE`` elements is
+    written in pieces of the last axis, each piece taking the positions
+    that fall inside it (the rest are redirected past its end), so every
+    scatter the compiler sees stays small."""
+    cap = buf.shape[-1]
+    lead = buf.size // max(cap, 1)
+    piece = max(SCATTER_PIECE // max(lead, 1), 1024)
+    if cap <= piece:
+        return buf.at[..., pos].set(upd, mode="drop")
+    parts = []
+    for base in range(0, cap, piece):
+        size = min(piece, cap - base)
+        loc = jnp.where((pos >= base) & (pos < base + size), pos - base, size)
+        parts.append(buf[..., base:base + size].at[..., loc].set(
+            upd, mode="drop"))
+    return jnp.concatenate(parts, axis=-1)
+
+
 def _scatter_pos(counts, starts, out_cap, sentinel):
     """Flat destinations of one chunk's (row, slot) cells: ``starts + offs``
     where the slot is occupied, the out-of-range ``sentinel`` (dropped by
@@ -193,9 +249,7 @@ def reassemble_device(idx_buf, dat_buf, cols, vals, counts, starts):
     also silently retires padding rows (count 0).
     """
     pos = _scatter_pos(counts, starts, cols.shape[1], idx_buf.shape[0])
-    idx_buf = idx_buf.at[pos].set(cols, mode="drop")
-    dat_buf = dat_buf.at[pos].set(vals, mode="drop")
-    return idx_buf, dat_buf
+    return scatter_drop(idx_buf, pos, cols), scatter_drop(dat_buf, pos, vals)
 
 
 def reassemble_device_batched(idx_buf, dat_buf_b, cols, vals_b, counts, starts):
@@ -206,9 +260,8 @@ def reassemble_device_batched(idx_buf, dat_buf_b, cols, vals_b, counts, starts):
     (batch, R_pad, out_cap).
     """
     pos = _scatter_pos(counts, starts, cols.shape[1], idx_buf.shape[0])
-    idx_buf = idx_buf.at[pos].set(cols, mode="drop")
-    dat_buf_b = dat_buf_b.at[:, pos].set(vals_b, mode="drop")
-    return idx_buf, dat_buf_b
+    return scatter_drop(idx_buf, pos, cols), scatter_drop(dat_buf_b, pos,
+                                                          vals_b)
 
 
 # ---------------------------------------------------------------------------
@@ -245,9 +298,9 @@ def reassemble_segment(seg_idx, seg_dat, dest, off, cols, vals, counts,
     loc_starts = off + _exclusive_cumsum(counts)
     pos = _scatter_pos(counts, loc_starts, out_cap, seg_idx.shape[0])
     offs = jnp.arange(out_cap, dtype=jnp.int32)[None, :]
-    seg_idx = seg_idx.at[pos].set(cols, mode="drop")
-    seg_dat = seg_dat.at[pos].set(vals, mode="drop")
-    dest = dest.at[pos].set(fin_starts[:, None] + offs, mode="drop")
+    seg_idx = scatter_drop(seg_idx, pos, cols)
+    seg_dat = scatter_drop(seg_dat, pos, vals)
+    dest = scatter_drop(dest, pos, fin_starts[:, None] + offs)
     return seg_idx, seg_dat, dest, off + jnp.sum(counts)
 
 
@@ -261,9 +314,9 @@ def reassemble_segment_batched(seg_idx, seg_dat_b, dest, off, cols, vals_b,
     loc_starts = off + _exclusive_cumsum(counts)
     pos = _scatter_pos(counts, loc_starts, out_cap, seg_idx.shape[0])
     offs = jnp.arange(out_cap, dtype=jnp.int32)[None, :]
-    seg_idx = seg_idx.at[pos].set(cols, mode="drop")
-    seg_dat_b = seg_dat_b.at[:, pos].set(vals_b, mode="drop")
-    dest = dest.at[pos].set(fin_starts[:, None] + offs, mode="drop")
+    seg_idx = scatter_drop(seg_idx, pos, cols)
+    seg_dat_b = scatter_drop(seg_dat_b, pos, vals_b)
+    dest = scatter_drop(dest, pos, fin_starts[:, None] + offs)
     return seg_idx, seg_dat_b, dest, off + jnp.sum(counts)
 
 
@@ -271,15 +324,13 @@ def merge_segments(idx_buf, dat_buf, seg_idx, seg_dat, dest):
     """Merge one shard's packed segment into the final CSR buffers: a
     single destination-mapped scatter per shard (unused segment slots
     carry the out-of-range sentinel and are dropped)."""
-    idx_buf = idx_buf.at[dest].set(seg_idx, mode="drop")
-    dat_buf = dat_buf.at[dest].set(seg_dat, mode="drop")
-    return idx_buf, dat_buf
+    return (scatter_drop(idx_buf, dest, seg_idx),
+            scatter_drop(dat_buf, dest, seg_dat))
 
 
 def merge_segments_batched(idx_buf, dat_buf_b, seg_idx, seg_dat_b, dest):
-    idx_buf = idx_buf.at[dest].set(seg_idx, mode="drop")
-    dat_buf_b = dat_buf_b.at[:, dest].set(seg_dat_b, mode="drop")
-    return idx_buf, dat_buf_b
+    return (scatter_drop(idx_buf, dest, seg_idx),
+            scatter_drop(dat_buf_b, dest, seg_dat_b))
 
 
 def merge_segments_host(idx_buf, dat_buf, seg_idx, seg_dat, dest):

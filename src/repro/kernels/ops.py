@@ -1,6 +1,6 @@
 """Jit'd public wrappers: Pallas on TPU, interpret-mode Pallas or XLA on CPU.
 
-``backend`` resolution:
+``backend`` resolution (``kernels.backend.resolve_backend``):
   * "pallas"    — compiled Pallas (TPU target).
   * "interpret" — Pallas kernel body executed in Python (CPU validation).
   * "xla"       — pure-jnp fallback (also the software-only / "without AIA"
@@ -8,25 +8,14 @@
 """
 from __future__ import annotations
 
-import os
-from typing import Literal
-
-import jax
-
 from repro.kernels import aia_gather as _aia
 from repro.kernels import ref as _ref
 from repro.kernels import spgemm_bsr as _bsr
 from repro.kernels import topk_spmm as _topk
+from repro.kernels.backend import Backend, resolve_backend
 
-Backend = Literal["auto", "pallas", "interpret", "xla"]
-
-
-def resolve_backend(backend: Backend = "auto") -> str:
-    if backend != "auto":
-        return backend
-    if os.environ.get("REPRO_KERNEL_BACKEND"):
-        return os.environ["REPRO_KERNEL_BACKEND"]
-    return "pallas" if jax.default_backend() == "tpu" else "xla"
+__all__ = ["Backend", "resolve_backend", "aia_ranged_gather", "gather_rows",
+           "bsr_spmm", "hash_accumulate", "topk_spmm", "block_topk_spmm"]
 
 
 def aia_ranged_gather(x, idx, r: int = 1, backend: Backend = "auto"):

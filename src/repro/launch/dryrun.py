@@ -33,7 +33,7 @@ import jax
 from repro.configs import ARCH_IDS, SHAPE_SETS, get_config
 from repro.configs.base import ArchConfig, ShapeSpec
 from repro.launch import specs as sp
-from repro.launch.mesh import make_production_mesh, use_mesh
+from repro.launch.mesh import make_production_mesh
 from repro.launch.sharding import make_shardings
 from repro.models import transformer as tf
 from repro.optim import adamw
@@ -72,7 +72,7 @@ def lower_cell(cfg: ArchConfig, shape: ShapeSpec, mesh, verbose=True):
     """Lower + compile one cell; returns the analysis record."""
     sh = make_shardings(mesh)
     t0 = time.monotonic()
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         if shape.kind == "train":
             step = make_train_step(cfg, adamw(3e-4), sh)
             state = sp.train_state_sds(cfg, mesh)
@@ -104,8 +104,6 @@ def lower_cell(cfg: ArchConfig, shape: ShapeSpec, mesh, verbose=True):
 
     ma = compiled.memory_analysis()
     ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):  # jax <= 0.4.x: one dict per program
-        ca = ca[0] if ca else {}
     hlo = compiled.as_text()
     coll = collective_bytes_from_hlo(hlo)
     rec = {

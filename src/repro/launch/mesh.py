@@ -1,4 +1,4 @@
-"""Mesh construction + version-compat helpers (multi-pod dry-run contract).
+"""Mesh construction (multi-pod dry-run contract + the SpGEMM shard mesh).
 
 ``make_production_mesh`` is a FUNCTION (importing this module never touches
 jax device state): single-pod = (16, 16) chips over ("data", "model");
@@ -7,45 +7,31 @@ pods.  The ``pod`` axis carries only data parallelism + the cross-pod
 gradient all-reduce (optionally int8-compressed).
 
 ``make_spgemm_mesh`` builds the 1-D ``("shard",)`` mesh the sharded SpGEMM
-executor partitions ``GroupPlan`` row ranges over; ``use_mesh`` papers over
-the ``jax.set_mesh`` (jax >= 0.6) vs legacy ``with mesh:`` context split so
-the same sharded code runs on every supported jax.
+executor partitions ``GroupPlan`` row ranges over.  Ambient mesh contexts
+are entered with ``jax.set_mesh``.
 """
 from __future__ import annotations
 
 import jax
 
 
-def compat_make_mesh(shape, axes, devices=None):
-    """``jax.make_mesh`` where it exists (jax >= 0.4.35); otherwise the
-    ``mesh_utils`` + ``Mesh`` construction every earlier jax supports."""
-    if hasattr(jax, "make_mesh"):
-        return jax.make_mesh(shape, axes, devices=devices)
-    import numpy as np
-    from jax.experimental import mesh_utils
-    from jax.sharding import Mesh
-
-    if devices is not None:
-        return Mesh(np.asarray(devices).reshape(shape), axes)
-    return Mesh(mesh_utils.create_device_mesh(shape), axes)
-
-
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat_make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes)
 
 
 def make_test_mesh(shape=(2, 2), axes=("data", "model")):
     """Small mesh for multi-device unit tests (host platform)."""
-    return compat_make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes)
 
 
 def make_spgemm_mesh(n_devices: int | None = None):
     """1-D ``("shard",)`` mesh for the sharded SpGEMM executor.
 
-    Uses the first ``n_devices`` visible devices (all of them by default).
-    On a host platform, force the device count with
+    Uses the first ``n_devices`` devices of ``jax.devices()`` (all of them
+    by default): the chips of a TPU host as they are.  Only a CPU host
+    needs its device count forced, with
     ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` *before* jax is
     imported (``benchmarks/run.py --devices N`` does this for you).
     """
@@ -54,20 +40,8 @@ def make_spgemm_mesh(n_devices: int | None = None):
     if not 1 <= n <= len(devs):
         raise ValueError(
             f"requested {n} shard devices but only {len(devs)} are visible; "
-            "set XLA_FLAGS=--xla_force_host_platform_device_count before "
+            "on a CPU host, set "
+            "XLA_FLAGS=--xla_force_host_platform_device_count before "
             "importing jax")
-    return compat_make_mesh((n,), ("shard",), devices=devs[:n])
+    return jax.make_mesh((n,), ("shard",), devices=devs[:n])
 
-
-def use_mesh(mesh):
-    """Ambient-mesh context manager across jax versions.
-
-    ``jax.set_mesh`` where it exists (jax >= 0.6); otherwise the legacy
-    ``with mesh:`` resource-env context, under which
-    ``with_sharding_constraint`` resolves bare ``PartitionSpec``s the same
-    way.  Always enter the mesh through this helper so sharded code paths
-    (and ``tests/test_distributed.py``) run on every supported jax.
-    """
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh  # jax.sharding.Mesh is itself a context manager

@@ -38,32 +38,45 @@ def run_lm(args) -> None:
         print(f"[serve] req{i}: prompt={list(r.prompt)} -> {r.out_tokens}")
 
 
+def spgemm_trace(n: int = 64, density: float = 0.1, patterns: int = 6,
+                 requests: int = 64, tenants: int = 4, zipf: float = 1.2,
+                 seed: int = 0):
+    """Synthetic multi-tenant SpGEMM traffic: ``[(tenant, a, b), ...]``.
+
+    ``patterns`` random ``n × n`` sparsity masks with Zipf-distributed
+    popularity (a few hot patterns dominate, which is what makes
+    coalescing pay); each request carries fresh values on its pattern's A
+    and the pattern's shared B, from tenants taken round-robin.
+    """
+    from repro.sparse.formats import csr_from_dense
+
+    rng = np.random.default_rng(seed)
+    masks = [rng.random((n, n)) < density for _ in range(patterns)]
+    b_side = [csr_from_dense((m * rng.standard_normal((n, n)))
+                             .astype(np.float32)) for m in masks]
+    ranks = np.arange(1, patterns + 1, dtype=np.float64)
+    popularity = ranks ** -zipf
+    popularity /= popularity.sum()
+    trace = []
+    for i in range(requests):
+        pid = int(rng.choice(patterns, p=popularity))
+        vals = rng.standard_normal((n, n)).astype(np.float32)
+        a = csr_from_dense((masks[pid] * vals).astype(np.float32))
+        trace.append((f"tenant{i % tenants}", a, b_side[pid]))
+    return trace
+
+
 def run_spgemm(args) -> None:
     """Drive the SpGEMMService over Zipf-popular synthetic patterns."""
     from repro.serve import SpGEMMService
-    from repro.sparse.formats import csr_from_dense
-
-    rng = np.random.default_rng(args.seed)
-    n = args.n
-    masks = [rng.random((n, n)) < args.density for _ in range(args.patterns)]
-    b_side = [csr_from_dense((m * rng.standard_normal((n, n)))
-                             .astype(np.float32)) for m in masks]
-
-    def fresh(pid):
-        vals = rng.standard_normal((n, n)).astype(np.float32)
-        return csr_from_dense((masks[pid] * vals).astype(np.float32))
 
     svc = SpGEMMService(max_batch=args.max_batch, max_wait=args.max_wait,
                         max_queue=args.max_queue)
-    # Zipf-distributed pattern popularity: a few hot patterns dominate,
-    # which is what makes coalescing pay.
-    ranks = np.arange(1, args.patterns + 1, dtype=np.float64)
-    popularity = ranks ** -args.zipf
-    popularity /= popularity.sum()
-    for i in range(args.requests):
-        pid = int(rng.choice(args.patterns, p=popularity))
-        tenant = f"tenant{i % args.tenants}"
-        svc.submit(tenant, fresh(pid), b_side[pid])
+    for tenant, a, b in spgemm_trace(
+            n=args.n, density=args.density, patterns=args.patterns,
+            requests=args.requests, tenants=args.tenants, zipf=args.zipf,
+            seed=args.seed):
+        svc.submit(tenant, a, b)
     svc.flush()
     s = svc.stats()
     print(f"[spgemm-serve] {s['requests_completed']} requests in "
@@ -101,6 +114,9 @@ def main():
     ap.add_argument("--max-queue", type=int, default=1024)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     if args.spgemm:
         run_spgemm(args)
     else:

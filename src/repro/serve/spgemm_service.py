@@ -344,6 +344,7 @@ class SpGEMMService:
         self._deadline_exceeded = 0
         self._retries = 0
         self._quarantined = 0
+        self._replayed = 0
 
     # ------------------------------------------------------------------
     # Request path
@@ -521,7 +522,11 @@ class SpGEMMService:
                 # fail a whole micro-batch.  Replay every member alone;
                 # innocents complete (bit-exact — the per-request loop is
                 # the batched lane's reference), the poison request is
-                # quarantined with its own error.
+                # quarantined with its own error.  Every replay is
+                # counted: a batched lane that fails for all traffic (e.g.
+                # a program that does not compile) must not pass for a
+                # healthy one.
+                self._replayed += len(reqs)
                 results = [self._run_isolated(r, plan, lead, kwargs)
                            for r in reqs]
         now = self._clock()
@@ -564,6 +569,9 @@ class SpGEMMService:
           while queued (expired at dispatch, never executed).
         * ``retries`` — shed-retry backoff attempts submit made before
           queueing or shedding (``submit(..., retries=)``).
+        * ``replayed`` — batch members re-run alone after their
+          micro-batch dispatch failed (whether or not the replay then
+          succeeded).
         * ``quarantined`` — requests that failed an isolated replay after
           a micro-batch dispatch failure and carry their own error
           (docs/resilience.md).
@@ -592,6 +600,7 @@ class SpGEMMService:
             "latency_p99_ms": p99,
             "deadline_exceeded": self._deadline_exceeded,
             "retries": self._retries,
+            "replayed": self._replayed,
             "quarantined": self._quarantined,
             "tenants": {tid: st.stats()
                         for tid, st in sorted(self._tenants.items())},

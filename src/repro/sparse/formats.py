@@ -23,6 +23,7 @@ All containers are registered pytrees: array fields are leaves, the logical
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Tuple
 
 import jax
@@ -249,22 +250,23 @@ def ell_to_dense(a: ELL) -> jax.Array:
     return out[:, :m]
 
 
+@functools.partial(jax.jit, static_argnames=("k_cap",))
 def csr_to_ell(a: CSR, k_cap: int) -> ELL:
-    """CSR -> ELL with static per-row capacity ``k_cap`` (jit-compatible)."""
-    n = a.n_rows
-    rid = a.row_ids()
-    p = jnp.arange(a.capacity, dtype=jnp.int32)
-    # slot's position within its row
-    within = p - jnp.take(a.indptr, jnp.clip(rid, 0, n), mode="clip")
-    valid = a.valid_mask() & (within < k_cap)
-    srow = jnp.where(valid, rid, n)
-    scol = jnp.where(valid, within, 0)
-    indices = jnp.full((n + 1, k_cap), -1, jnp.int32).at[srow, scol].set(
-        jnp.where(valid, a.indices, -1)
-    )[:n]
-    data = jnp.zeros((n + 1, k_cap), a.data.dtype).at[srow, scol].set(
-        jnp.where(valid, a.data, 0)
-    )[:n]
+    """CSR -> ELL with static per-row capacity ``k_cap`` (jit-compatible).
+
+    Each row gathers its first ``k_cap`` slots (a row's later entries are
+    dropped).  A gather rather than a scatter into the (n, k_cap) planes:
+    the TPU compiler takes seconds over such a scatter at published matrix
+    sizes."""
+    starts = a.indptr[:-1]
+    offs = jnp.arange(k_cap, dtype=jnp.int32)[None, :]
+    valid = offs < (a.indptr[1:] - starts)[:, None]
+    pos = jnp.clip(starts[:, None] + offs, 0, max(a.capacity - 1, 0))
+    if a.capacity == 0:
+        return ELL(jnp.full((a.n_rows, k_cap), -1, jnp.int32),
+                   jnp.zeros((a.n_rows, k_cap), a.data.dtype), a.shape)
+    indices = jnp.where(valid, a.indices[pos], -1).astype(jnp.int32)
+    data = jnp.where(valid, a.data[pos], 0).astype(a.data.dtype)
     return ELL(indices, data, a.shape)
 
 

@@ -1,11 +1,4 @@
-"""Hypothesis import shim: real hypothesis when installed, tiny fallback not.
-
-The tier-1 suite must collect and run in environments without
-``hypothesis`` (the container bakes in the jax toolchain only).  When the
-real library is available it is re-exported unchanged; otherwise ``given``
-degrades to a deterministic sampler that draws a handful of examples per
-strategy — enough to keep the property tests exercising the code paths,
-without shrinking/reporting machinery.
+"""Hypothesis re-export: property-based test modules import from here.
 
 Usage in test modules::
 
@@ -13,77 +6,7 @@ Usage in test modules::
 """
 from __future__ import annotations
 
-try:
-    from hypothesis import given, settings
-    from hypothesis import strategies as st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
-    HAVE_HYPOTHESIS = True
-except ImportError:  # pragma: no cover - exercised only without hypothesis
-    import functools
-    import inspect
-    import random
-
-    HAVE_HYPOTHESIS = False
-    _FALLBACK_EXAMPLES = 5
-
-    class _Strategy:
-        def __init__(self, draw):
-            self._draw = draw
-
-        def example(self, rng):
-            return self._draw(rng)
-
-    class _Strategies:
-        @staticmethod
-        def integers(min_value, max_value):
-            return _Strategy(lambda rng: rng.randint(min_value, max_value))
-
-        @staticmethod
-        def floats(min_value, max_value):
-            return _Strategy(lambda rng: rng.uniform(min_value, max_value))
-
-        @staticmethod
-        def sampled_from(elements):
-            elements = list(elements)
-            return _Strategy(lambda rng: rng.choice(elements))
-
-        @staticmethod
-        def booleans():
-            return _Strategy(lambda rng: rng.random() < 0.5)
-
-    st = _Strategies()
-
-    def settings(*args, **kwargs):
-        """No-op stand-in for ``hypothesis.settings`` (accepts any config)."""
-        def deco(fn):
-            return fn
-        return deco
-
-    def given(*arg_strategies, **kw_strategies):
-        """Deterministic mini-``given``: a fixed-seed RNG draws
-        ``_FALLBACK_EXAMPLES`` examples per test and runs them all.
-        Positional strategies map to the *rightmost* parameters, matching
-        real hypothesis, and everything is passed by keyword."""
-        def deco(fn):
-            sig = inspect.signature(fn)
-            names = list(sig.parameters)
-            strategies = dict(kw_strategies)
-            if arg_strategies:
-                for name, strat in zip(names[-len(arg_strategies):],
-                                       arg_strategies):
-                    strategies[name] = strat
-
-            @functools.wraps(fn)
-            def wrapper(*args, **kwargs):
-                rng = random.Random(f"{fn.__module__}.{fn.__qualname__}")
-                for _ in range(_FALLBACK_EXAMPLES):
-                    drawn = {k: s.example(rng) for k, s in strategies.items()}
-                    fn(*args, **kwargs, **drawn)
-
-            # Hide the drawn parameters from pytest's fixture resolution
-            # (the real hypothesis does the same via @impersonate).
-            remaining = [p for name, p in sig.parameters.items()
-                         if name not in strategies]
-            wrapper.__signature__ = sig.replace(parameters=remaining)
-            return wrapper
-        return deco
+__all__ = ["given", "settings", "st"]

@@ -196,3 +196,30 @@ def test_gnn_topk_vs_dense_agree_when_k_full():
 def dataclasses_replace(cfg, **kw):
     import dataclasses
     return dataclasses.replace(cfg, **kw)
+
+
+def test_mcl_drop_zeros_compacts_pruned_entries():
+    from repro.apps.markov_clustering import drop_zeros
+
+    x = np.array([[0.5, 0.0, 0.25], [0.0, 0.0, 1.0], [2.0, 0.0, 0.0]],
+                 np.float32)
+    g = csr_from_dense(x)
+    g = type(g)(g.indptr, g.indices, g.data.at[0].set(0.0), g.shape)
+    c = drop_zeros(g)
+    assert int(np.asarray(c.indptr)[-1]) == 3
+    assert c.indices.shape[0] == 4  # pow2-quantized capacity
+    x[0, 0] = 0.0
+    np.testing.assert_array_equal(np.asarray(csr_to_dense(c)), x)
+
+
+def test_mcl_iterates_on_the_pruned_structure():
+    """Pruned entries leave the structure, so the next expansion only
+    multiplies what survived: every stored entry of the result is nonzero
+    and each column keeps at most k of them."""
+    g = rmat_graph(64, 4.0, seed=1)
+    res = mcl(g, e=2, r=2.0, k=4, max_iters=3, tol=0.0)
+    nnz = int(np.asarray(res.matrix.indptr)[-1])
+    data = np.asarray(res.matrix.data)[:nnz]
+    assert np.all(data != 0)
+    cols = np.asarray(res.matrix.indices)[:nnz]
+    assert np.bincount(cols, minlength=64).max() <= 4

@@ -126,11 +126,26 @@ def test_ell_fixed_rejects_auto():
 
 
 def test_static_bin_engines_backend_seed():
-    assert executor.static_bin_engines("tpu") == ("fused_hash",) * 4
-    assert executor.static_bin_engines("cpu") == ("sort",) * 4
-    assert executor.static_bin_engines("gpu") == ("sort",) * 4
-    seed = executor.static_bin_engines()  # live backend
+    caps = (64, 1024, 8192, 16384)  # Table I, group 3 sized at plan time
+    assert executor.static_bin_engines(caps, "tpu") == ("fused_hash",) * 4
+    assert executor.static_bin_engines(caps, "cpu") == ("sort",) * 4
+    assert executor.static_bin_engines(caps, "gpu") == ("sort",) * 4
+    seed = executor.static_bin_engines(caps)  # live backend
     assert len(seed) == 4 and all(e in executor.ENGINES for e in seed)
+
+
+def test_static_bin_engines_routes_oversized_tables_to_sort():
+    """A bin whose table exceeds the hash kernel's SMEM budget starts on
+    sort on TPU; the fused kernel mode sends such a table to the scan
+    engine instead of the Pallas kernel."""
+    from repro.kernels.hash_accum import MAX_TABLE_CAP
+
+    caps = (64, 1024, 8192, 2 * MAX_TABLE_CAP)
+    assert executor.static_bin_engines(caps, "tpu") == (
+        "fused_hash", "fused_hash", "fused_hash", "sort")
+    assert executor.static_bin_engines(caps, "cpu") == ("sort",) * 4
+    f32 = np.dtype(np.float32).str
+    assert executor._fused_kernel_mode(f32, 2 * MAX_TABLE_CAP) == "xla"
 
 
 # ---------------------------------------------------------------------------
@@ -260,7 +275,7 @@ def test_autotune_argmin_beats_seed():
     even when the seed engine was measured first."""
     a, b, plan = _multibin_operands()
     cache = executor.AutotuneCache()
-    seed = executor.static_bin_engines()
+    seed = executor.static_bin_engines(plan.table_capacities)
     best = next(e for e in executor.available_engines() if e != seed[0])
     timings = {(g, e): (50.0 if e == best else 100.0)
                for g in range(4) for e in executor.available_engines()}

@@ -2,10 +2,8 @@
 (XLA_FLAGS must be set before jax import, and must NOT leak into the main
 test session which expects 1 device).
 
-Mesh contexts go through ``launch.mesh.use_mesh`` (``jax.set_mesh`` on new
-jax, the legacy ``with mesh:`` resource env otherwise) and all array
-placement uses explicit ``NamedSharding``s, so these run on every
-supported jax version — no version skips."""
+Mesh contexts are entered with ``jax.set_mesh`` and all array placement
+uses explicit ``NamedSharding``s."""
 import os
 import subprocess
 import sys
@@ -33,7 +31,7 @@ def test_sharded_train_step_matches_single_device():
     from jax.sharding import NamedSharding, PartitionSpec as P
     from repro.configs import smoke_config
     from repro.models.transformer import init_transformer, train_loss
-    from repro.launch.mesh import make_test_mesh, use_mesh
+    from repro.launch.mesh import make_test_mesh
     from repro.launch.sharding import make_shardings
 
     cfg = dc.replace(smoke_config("granite-3-2b"), n_layers=2)
@@ -47,7 +45,7 @@ def test_sharded_train_step_matches_single_device():
     sh = make_shardings(mesh)
     from repro.models.transformer import param_specs
     specs = param_specs(cfg, params, model_size=2)
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         p_sharded = jax.tree.map(
             lambda x, s: jax.device_put(x, NamedSharding(mesh, s)), params, specs)
         b_sharded = jax.tree.map(
@@ -66,16 +64,15 @@ def test_elastic_checkpoint_reshard():
     import jax, jax.numpy as jnp, numpy as np
     from jax.sharding import NamedSharding, PartitionSpec as P
     from repro.checkpoint import save_checkpoint, restore_checkpoint
-    from repro.launch.mesh import compat_make_mesh
 
     tree = {"w": jnp.arange(64, dtype=jnp.float32).reshape(8, 8),
             "b": jnp.ones(8)}
-    mesh8 = compat_make_mesh((8,), ("data",))
+    mesh8 = jax.make_mesh((8,), ("data",))
     sharded = jax.device_put(tree["w"], NamedSharding(mesh8, P("data", None)))
     tree8 = {"w": sharded, "b": tree["b"]}
     with tempfile.TemporaryDirectory() as d:
         save_checkpoint(d, 1, tree8)
-        mesh4 = compat_make_mesh((4,), ("data",), devices=jax.devices()[:4])
+        mesh4 = jax.make_mesh((4,), ("data",), devices=jax.devices()[:4])
         sh4 = {"w": NamedSharding(mesh4, P(None, "data")), "b": None}
         restored = restore_checkpoint(d, 1, tree, shardings=sh4)
         np.testing.assert_array_equal(np.asarray(restored["w"]), np.asarray(tree["w"]))
@@ -92,9 +89,8 @@ def test_compressed_psum_shard_map():
     from jax.sharding import NamedSharding, PartitionSpec as P
     from jax.experimental.shard_map import shard_map
     from repro.optim import compressed_psum
-    from repro.launch.mesh import compat_make_mesh
 
-    mesh = compat_make_mesh((8,), ("data",))
+    mesh = jax.make_mesh((8,), ("data",))
     x = jnp.arange(64, dtype=jnp.float32).reshape(8, 8) / 13.0
 
     @functools.partial(shard_map, mesh=mesh, in_specs=P("data", None),
@@ -119,9 +115,8 @@ def test_pipeline_parallel_shard_map():
     import jax, jax.numpy as jnp, numpy as np
     from jax.sharding import PartitionSpec as P
     from repro.launch.pipeline import pipeline_apply
-    from repro.launch.mesh import compat_make_mesh
 
-    mesh = compat_make_mesh((4,), ("pipe",))
+    mesh = jax.make_mesh((4,), ("pipe",))
     # 4 stages, each a simple affine layer; verify against sequential apply
     rng = np.random.default_rng(0)
     ws = jnp.asarray(rng.standard_normal((4, 8, 8)).astype(np.float32) * 0.3)
@@ -175,7 +170,7 @@ def test_moe_shard_map_matches_unsharded():
     from repro.configs import smoke_config
     from repro.models.transformer import init_transformer, param_specs
     from repro.models.transformer import forward_hidden
-    from repro.launch.mesh import make_test_mesh, use_mesh
+    from repro.launch.mesh import make_test_mesh
     from repro.launch.sharding import make_shardings, UNSHARDED
 
     base = smoke_config("llama4-scout-17b-a16e")
@@ -195,18 +190,17 @@ def test_moe_shard_map_matches_unsharded():
     mesh = make_test_mesh((2, 2), ("data", "model"))
     sh = make_shardings(mesh)
     specs = param_specs(cfg_g, params, model_size=2)
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         ps = jax.tree.map(lambda x, s: jax.device_put(x, NamedSharding(mesh, s)),
                           params, specs)
         ts = jax.device_put(tokens, NamedSharding(mesh, P("data", None)))
         hs, _ = jax.jit(lambda p: forward_hidden(cfg_s, p, ts, sh))(ps)
-        if hasattr(jax, "set_mesh"):
-            # new jax: the mesh-sharded gspmd baseline is also correct —
-            # hold it to the same unsharded truth
-            hgm, _ = jax.jit(lambda p: forward_hidden(cfg_g, p, ts, sh))(ps)
-            np.testing.assert_allclose(np.asarray(hgm, np.float32),
-                                       np.asarray(hg, np.float32),
-                                       rtol=2e-3, atol=2e-4)
+        # the mesh-sharded gspmd baseline is also held to the same
+        # unsharded truth
+        hgm, _ = jax.jit(lambda p: forward_hidden(cfg_g, p, ts, sh))(ps)
+        np.testing.assert_allclose(np.asarray(hgm, np.float32),
+                                   np.asarray(hg, np.float32),
+                                   rtol=2e-3, atol=2e-4)
     # identical expert math; only the aux-loss *estimator* differs
     np.testing.assert_allclose(np.asarray(hs, np.float32),
                                np.asarray(hg, np.float32), rtol=2e-3, atol=2e-4)

@@ -79,7 +79,7 @@ def test_fused_spgemm_through_interpret_kernel(monkeypatch):
     from repro.sparse.formats import csr_from_dense
 
     monkeypatch.setenv("REPRO_KERNEL_BACKEND", "interpret")
-    assert executor._fused_kernel_mode(np.dtype(np.float32).str) \
+    assert executor._fused_kernel_mode(np.dtype(np.float32).str, 64) \
         == "interpret"
     rng = np.random.default_rng(7)
     x = np.where(rng.random((12, 12)) < 0.3,

@@ -67,7 +67,7 @@ def test_sweep_converges_cache_to_argmin(fixture):
         measure=lambda g, e: 10.0 if e == winner[g] else 100.0)
     key = executor.autotune_key(a, b, plan)
     assert cache.converged(key)
-    seed = executor.static_bin_engines()
+    seed = executor.static_bin_engines(plan.table_capacities)
     for g in range(4):
         expect = winner[g] if plan.group_sizes[g] > 0 else seed[g]
         assert record["assignment"][g] == expect

@@ -150,3 +150,26 @@ def test_ops_backends_agree():
     a = ops.aia_ranged_gather(x, idx, 1, backend="xla")
     b = ops.aia_ranged_gather(x, idx, 1, backend="interpret")
     np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+@pytest.mark.parametrize("platform,override,expect", [
+    ("tpu", None, False),            # TPU default: compiled
+    ("tpu", "interpret", True),      # asked for
+    ("tpu", "xla", RuntimeError),    # never interpret silently on TPU
+    ("cpu", None, True),             # off-TPU the interpreter is the only way
+    ("cpu", "pallas", False),
+])
+def test_resolve_interpret_policy(monkeypatch, platform, override, expect):
+    from repro.kernels import backend
+
+    monkeypatch.setattr(backend.jax, "default_backend", lambda: platform)
+    if override is None:
+        monkeypatch.delenv("REPRO_KERNEL_BACKEND", raising=False)
+    else:
+        monkeypatch.setenv("REPRO_KERNEL_BACKEND", override)
+    assert backend.resolve_interpret(True) is True
+    if expect is RuntimeError:
+        with pytest.raises(RuntimeError, match="Pallas kernel"):
+            backend.resolve_interpret(None)
+    else:
+        assert backend.resolve_interpret(None) is expect

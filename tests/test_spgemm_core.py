@@ -167,3 +167,73 @@ def test_property_spgemm_equals_dense(n, k, m, seed):
         np.asarray(csr_to_dense(a)) @ np.asarray(csr_to_dense(b)),
         rtol=1e-4, atol=1e-4,
     )
+
+
+# ---------------------------------------------------------------------------
+# Compile-friendly shapes: padded product streams, piecewise scatters,
+# equal row chunks
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("ip_cap,width", [(1, 1), (12, 16), (189, 256),
+                                          (1024, 1024), (1025, 2048),
+                                          (2955, 3072), (349281, 350208)])
+def test_stream_width(ip_cap, width):
+    from repro.core.phases import stream_width
+
+    assert stream_width(ip_cap) == width
+
+
+def test_padded_stream_changes_no_product():
+    """The padding slots are -1 keys with 0 values, which every engine
+    skips: the accumulated rows equal those of the unpadded stream."""
+    from repro.core import phases
+
+    rng = np.random.default_rng(3)
+    cols_a = jnp.asarray(rng.integers(-1, 6, (5, 3)), jnp.int32)
+    vals_a = jnp.asarray(rng.standard_normal((5, 3)), jnp.float32)
+    bi = jnp.asarray(rng.integers(-1, 9, (5, 3, 7)), jnp.int32)
+    bv = jnp.asarray(rng.standard_normal((5, 3, 7)), jnp.float32)
+    keys, vals = phases.combine_products(cols_a, vals_a, bi, bv)
+    assert keys.shape == (5, 32)
+    assert bool(jnp.all(keys[:, 21:] == -1)) and bool(jnp.all(vals[:, 21:] == 0))
+    for got, want in zip(phases.sort_unique(keys, vals, 9),
+                         phases.sort_unique(keys[:, :21], vals[:, :21], 9)):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+@pytest.mark.parametrize("lead", [(), (3,)])
+def test_scatter_drop_in_pieces_matches_one_scatter(monkeypatch, lead):
+    from repro.core import phases
+
+    rng = np.random.default_rng(0)
+    cap = 5000
+    buf = jnp.asarray(rng.standard_normal(lead + (cap,)), jnp.float32)
+    pos = jnp.asarray(rng.permutation(cap + 300)[:600].reshape(40, 15),
+                      jnp.int32)  # some positions past the end: dropped
+    upd = jnp.asarray(rng.standard_normal(lead + (40, 15)), jnp.float32)
+    want = buf.at[..., pos].set(upd, mode="drop")
+    monkeypatch.setattr(phases, "SCATTER_PIECE", 1024)
+    got = phases.scatter_drop(buf, pos, upd)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def test_partition_plan_chunks_of_a_group_pad_alike():
+    """A group split into several chunks pads every chunk to one length,
+    so the chunks share their compiled programs."""
+    from repro.core import executor
+    from repro.core.grouping import group_rows
+
+    rng = np.random.default_rng(2)
+    a = csr_from_dense(np.where(rng.random((300, 40)) < 0.05, 1.0, 0.0)
+                       .astype(np.float32))
+    b = csr_from_dense(np.where(rng.random((40, 30)) < 0.05, 1.0, 0.0)
+                       .astype(np.float32))
+    plan = group_rows(a, b)
+    items = executor.partition_plan(plan, np.diff(np.asarray(a.indptr)), 64)
+    by_group = {}
+    for it in items:
+        assert len(it.rows) <= 64
+        assert it.padded >= len(it.rows)
+        by_group.setdefault(it.group, set()).add(it.padded)
+    assert any(len([i for i in items if i.group == g]) > 1 for g in by_group)
+    assert all(len(lengths) == 1 for lengths in by_group.values())
