@@ -127,31 +127,38 @@ def train_gnn(
     ``mesh`` row-shards the per-layer aggregations (forward and backward)
     over the mesh's first axis via GSPMD sharding constraints.
     """
-    key = jax.random.PRNGKey(seed)
-    params = init_gnn(cfg, key)
-    opt = adamw(lr, weight_decay=0.0)
-    opt_state = opt.init(params)
-    x = jnp.asarray(x)
-    labels = jnp.asarray(labels)
-    mask = jnp.ones(labels.shape[0], jnp.float32)
+    with jax.profiler.TraceAnnotation("gnn.train"):
+        with jax.profiler.TraceAnnotation("gnn.init"):
+            key = jax.random.PRNGKey(seed)
+            params = init_gnn(cfg, key)
+            opt = adamw(lr, weight_decay=0.0)
+            opt_state = opt.init(params)
+        x = jnp.asarray(x)
+        labels = jnp.asarray(labels)
+        mask = jnp.ones(labels.shape[0], jnp.float32)
 
-    # The graph and features are arguments, not closed-over constants: a
-    # constant of this size would be folded into the program the compiler
-    # sees.
-    @jax.jit
-    def step(params, opt_state, a, x, labels, mask):
-        loss, grads = jax.value_and_grad(
-            lambda p: _loss_fn(cfg, p, a, x, labels, mask, mesh=mesh)
-        )(params)
-        grads, _ = clip_by_global_norm(grads, 1.0)
-        updates, opt_state = opt.update(grads, opt_state, params)
-        return apply_updates(params, updates), opt_state, loss
+        # The graph and features are arguments, not closed-over constants: a
+        # constant of this size would be folded into the program the
+        # compiler sees.
+        @jax.jit
+        def step(params, opt_state, a, x, labels, mask):
+            # a step's Python body runs only while JAX traces it
+            with jax.profiler.TraceAnnotation("gnn.trace"):
+                loss, grads = jax.value_and_grad(
+                    lambda p: _loss_fn(cfg, p, a, x, labels, mask, mesh=mesh)
+                )(params)
+                grads, _ = clip_by_global_norm(grads, 1.0)
+                updates, opt_state = opt.update(grads, opt_state, params)
+                return apply_updates(params, updates), opt_state, loss
 
-    history = []
-    for _ in range(n_steps):
-        params, opt_state, loss = step(params, opt_state, a, x, labels, mask)
-        history.append(float(loss))
-    return params, history
+        history = []
+        for _ in range(n_steps):
+            with jax.profiler.TraceAnnotation("gnn.step"):
+                params, opt_state, loss = step(params, opt_state, a, x,
+                                               labels, mask)
+            with jax.profiler.TraceAnnotation("gnn.loss_read"):
+                history.append(float(loss))
+        return params, history
 
 
 # ---------------------------------------------------------------------------

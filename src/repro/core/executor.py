@@ -967,30 +967,31 @@ class OperandCache:
     @staticmethod
     def _build(b: CSR, kb_cap: int, devices,
                footprints=None) -> _OperandEntry:
-        b_ell = csr_to_ell(b, kb_cap)
-        n_rows = int(b_ell.indices.shape[0])
-        shards = []
-        for s, dev in enumerate(devices):
-            fp = None if footprints is None else footprints[s]
-            if fp is None:
-                shard = (replicate_to(b_ell.indices, dev),
-                         replicate_to(b_ell.data, dev), None)
-                rows_placed = n_rows
-            else:
-                shard = place_operand_block(b_ell.indices, b_ell.data,
-                                            fp, dev)
-                rows_placed = len(fp)
-            _OPERAND_STATS["operand_bytes_placed"] += sum(
-                int(x.nbytes) for x in shard if x is not None)
-            _OPERAND_STATS["operand_rows_footprint"] += rows_placed
-            _OPERAND_STATS["operand_rows_total"] += n_rows
-            shards.append(shard)
-        return _OperandEntry(
-            source=(b.indptr, b.indices, b.data),
-            b_ell=b_ell,
-            shards=shards,
-            footprints=None if footprints is None else list(footprints),
-        )
+        with jax.profiler.TraceAnnotation("spgemm.operands.build"):
+            b_ell = csr_to_ell(b, kb_cap)
+            n_rows = int(b_ell.indices.shape[0])
+            shards = []
+            for s, dev in enumerate(devices):
+                fp = None if footprints is None else footprints[s]
+                if fp is None:
+                    shard = (replicate_to(b_ell.indices, dev),
+                             replicate_to(b_ell.data, dev), None)
+                    rows_placed = n_rows
+                else:
+                    shard = place_operand_block(b_ell.indices, b_ell.data,
+                                                fp, dev)
+                    rows_placed = len(fp)
+                _OPERAND_STATS["operand_bytes_placed"] += sum(
+                    int(x.nbytes) for x in shard if x is not None)
+                _OPERAND_STATS["operand_rows_footprint"] += rows_placed
+                _OPERAND_STATS["operand_rows_total"] += n_rows
+                shards.append(shard)
+            return _OperandEntry(
+                source=(b.indptr, b.indices, b.data),
+                b_ell=b_ell,
+                shards=shards,
+                footprints=None if footprints is None else list(footprints),
+            )
 
     def b_operands(self, b: CSR, kb_cap: int, devices,
                    footprints=None) -> _OperandEntry:
@@ -999,27 +1000,28 @@ class OperandCache:
         The key is the identity of B's buffers + ``kb_cap`` + the device
         set + the footprint fingerprint; NumPy-backed CSRs are never
         cached (mutable buffers can be edited in place)."""
-        if not all(isinstance(x, jax.Array)
-                   for x in (b.indptr, b.indices, b.data)):
-            _OPERAND_STATS["operand_misses"] += 1
-            return self._build(b, kb_cap, devices,
-                               footprints)  # mutable: never cache
-        key = (
-            id(b.indptr), id(b.indices), id(b.data), int(kb_cap),
-            tuple(getattr(d, "id", None) for d in devices),
-            _footprint_fingerprint(footprints),
-        )
-        entry = self._entries.get(key)
-        if entry is None:
-            _OPERAND_STATS["operand_misses"] += 1
-            entry = self._build(b, kb_cap, devices, footprints)
-            self._entries[key] = entry
-            while len(self._entries) > self.max_entries:
-                self._entries.popitem(last=False)
-        else:
-            _OPERAND_STATS["operand_hits"] += 1
-            self._entries.move_to_end(key)
-        return entry
+        with jax.profiler.TraceAnnotation("spgemm.operands"):
+            if not all(isinstance(x, jax.Array)
+                       for x in (b.indptr, b.indices, b.data)):
+                _OPERAND_STATS["operand_misses"] += 1
+                return self._build(b, kb_cap, devices,
+                                   footprints)  # mutable: never cache
+            key = (
+                id(b.indptr), id(b.indices), id(b.data), int(kb_cap),
+                tuple(getattr(d, "id", None) for d in devices),
+                _footprint_fingerprint(footprints),
+            )
+            entry = self._entries.get(key)
+            if entry is None:
+                _OPERAND_STATS["operand_misses"] += 1
+                entry = self._build(b, kb_cap, devices, footprints)
+                self._entries[key] = entry
+                while len(self._entries) > self.max_entries:
+                    self._entries.popitem(last=False)
+            else:
+                _OPERAND_STATS["operand_hits"] += 1
+                self._entries.move_to_end(key)
+            return entry
 
 
 _OPERAND_CACHE = OperandCache()
@@ -1273,6 +1275,14 @@ def _autotune_assignment(a, b, plan, gather, row_chunk, mesh, pipeline,
     return cache.assignment_for(autotune_key(a, b, plan), plan, measure)
 
 
+def _jit_named(program: Callable, name: str) -> Callable:
+    """``jax.jit(program)`` compiled as the module ``jit_<name>``: the name a
+    profiler trace gives the program's device ops, so each executor phase
+    (and each Table-I table capacity, ``_t<cap>``) is found by name."""
+    program.__name__ = program.__qualname__ = name
+    return jax.jit(program)
+
+
 def _build_enumerate(a_cap: int, kb_cap: int, gather: str,
                      remapped: bool = False) -> Callable:
     """Compile the product-enumeration program: A-row gather → B-row gather
@@ -1287,7 +1297,6 @@ def _build_enumerate(a_cap: int, kb_cap: int, gather: str,
     ids, so the products are bit-identical either way."""
     gat = GATHERS[gather]
 
-    @jax.jit
     def program(a_indptr, a_indices, a_data, rows, b_idx, b_val, remap=None):
         cols_a, vals_a = phases.gather_group_rows(
             a_indptr, a_indices, a_data, rows, a_cap
@@ -1297,18 +1306,25 @@ def _build_enumerate(a_cap: int, kb_cap: int, gather: str,
         bi, bv = gat(b_idx, b_val, cols_a, kb_cap)
         return phases.combine_products(cols_a, vals_a, bi, bv)
 
-    return program
+    return _jit_named(program, "spgemm_enumerate")
 
 
 def _build_allocate(table_cap: int, engine: str) -> Callable:
     eng = get_engine(engine)
-    return jax.jit(lambda keys: eng.allocate(keys, table_cap))
+
+    def program(keys):
+        return eng.allocate(keys, table_cap)
+
+    return _jit_named(program, f"spgemm_allocate_t{table_cap}")
 
 
 def _build_accumulate(table_cap: int, out_cap: int, engine: str) -> Callable:
     eng = get_engine(engine)
-    return jax.jit(
-        lambda keys, vals: eng.accumulate(keys, vals, table_cap, out_cap))
+
+    def program(keys, vals):
+        return eng.accumulate(keys, vals, table_cap, out_cap)
+
+    return _jit_named(program, f"spgemm_accumulate_t{table_cap}")
 
 
 def _build_enumerate_batched(a_cap: int, kb_cap: int, gather: str,
@@ -1319,7 +1335,6 @@ def _build_enumerate_batched(a_cap: int, kb_cap: int, gather: str,
     sizes the whole batch.  ``remapped`` as in ``_build_enumerate``."""
     gat = BATCHED_GATHERS[gather]
 
-    @jax.jit
     def program(a_indptr, a_indices, a_data_b, rows, b_idx, b_val_b,
                 remap=None):
         cols_a, vals_a_b = phases.gather_group_rows_batched(
@@ -1330,7 +1345,7 @@ def _build_enumerate_batched(a_cap: int, kb_cap: int, gather: str,
         bi, bv_b = gat(b_idx, b_val_b, cols_a, kb_cap)
         return phases.combine_products_batched(cols_a, vals_a_b, bi, bv_b)
 
-    return program
+    return _jit_named(program, "spgemm_benumerate")
 
 
 def _build_accumulate_batched(table_cap: int, out_cap: int,
@@ -1341,13 +1356,12 @@ def _build_accumulate_batched(table_cap: int, out_cap: int,
     (R,))."""
     eng = get_engine(engine)
 
-    @jax.jit
     def program(keys, vals_b):
         cols, vals, counts = jax.vmap(
             lambda v: eng.accumulate(keys, v, table_cap, out_cap))(vals_b)
         return cols[0], vals, counts[0]
 
-    return program
+    return _jit_named(program, f"spgemm_baccumulate_t{table_cap}")
 
 
 def _build_fused(a_cap: int, kb_cap: int, gather: str, table_cap: int,
@@ -1361,7 +1375,6 @@ def _build_fused(a_cap: int, kb_cap: int, gather: str, table_cap: int,
     runs (``out_cap`` comes from the plan's Alg. 1 bounds)."""
     gat = GATHERS[gather]
 
-    @jax.jit
     def program(a_indptr, a_indices, a_data, rows, b_idx, b_val, remap=None):
         cols_a, vals_a = phases.gather_group_rows(
             a_indptr, a_indices, a_data, rows, a_cap
@@ -1373,7 +1386,7 @@ def _build_fused(a_cap: int, kb_cap: int, gather: str, table_cap: int,
         return phases.fused_hash_sorted(keys, vals, table_cap, out_cap,
                                         kernel=kernel)
 
-    return program
+    return _jit_named(program, f"spgemm_fused_t{table_cap}")
 
 
 def _build_fused_batched(a_cap: int, kb_cap: int, gather: str,
@@ -1386,7 +1399,6 @@ def _build_fused_batched(a_cap: int, kb_cap: int, gather: str,
     member's values, like the batched accumulate."""
     gat = BATCHED_GATHERS[gather]
 
-    @jax.jit
     def program(a_indptr, a_indices, a_data_b, rows, b_idx, b_val_b,
                 remap=None):
         cols_a, vals_a_b = phases.gather_group_rows_batched(
@@ -1401,7 +1413,7 @@ def _build_fused_batched(a_cap: int, kb_cap: int, gather: str,
             keys, v, table_cap, out_cap, kernel="xla"))(vals_b)
         return cols[0], vals, counts[0]
 
-    return program
+    return _jit_named(program, f"spgemm_bfused_t{table_cap}")
 
 
 def _build_segment() -> Callable:
@@ -1815,15 +1827,16 @@ def _coalesce_and_size(pend: List[tuple], n: int):
     ``pend`` entries are ``(item, padded, keys, vals, alloc_counts)``;
     returns ``(unique_counts, indptr, nnz, cap)``.
     """
-    unique_counts = _coalesced_sync([p[4] for p in pend]) if pend else []
-    counts_all = np.zeros(n, np.int64)
-    for (item, _, _, _, _), uc in zip(pend, unique_counts):
-        counts_all[item.rows] = uc[: len(item.rows)]
-    indptr64 = np.zeros(n + 1, np.int64)
-    np.cumsum(counts_all, out=indptr64[1:])
-    nnz = int(indptr64[-1])
-    cap = _int32_nnz_capacity(nnz)
-    return unique_counts, indptr64.astype(np.int32), nnz, cap
+    with jax.profiler.TraceAnnotation("spgemm.sync"):
+        unique_counts = _coalesced_sync([p[4] for p in pend]) if pend else []
+        counts_all = np.zeros(n, np.int64)
+        for (item, _, _, _, _), uc in zip(pend, unique_counts):
+            counts_all[item.rows] = uc[: len(item.rows)]
+        indptr64 = np.zeros(n + 1, np.int64)
+        np.cumsum(counts_all, out=indptr64[1:])
+        nnz = int(indptr64[-1])
+        cap = _int32_nnz_capacity(nnz)
+        return unique_counts, indptr64.astype(np.int32), nnz, cap
 
 
 def _chunk_starts(indptr: np.ndarray, rows: np.ndarray, padded: int,
@@ -2092,9 +2105,10 @@ def execute_plan(
                 f"device budget is {budget}; stream the call instead — "
                 "spgemm_streamed with tile_rows small enough that every "
                 "tile's estimate fits the budget")
-    gather, kb_cap, ncol_cap, devices, items, footprints = _setup_execution(
-        a, b, plan, engine, gather, row_chunk, mesh,
-        group_engines=group_engines, operands=operands)
+    with jax.profiler.TraceAnnotation("spgemm.setup"):
+        gather, kb_cap, ncol_cap, devices, items, footprints = (
+            _setup_execution(a, b, plan, engine, gather, row_chunk, mesh,
+                             group_engines=group_engines, operands=operands))
     n = a.n_rows
     dtype = np.dtype(a.data.dtype)  # no host round-trip: dtype is metadata
     dt = dtype.str
@@ -2130,48 +2144,50 @@ def execute_plan(
         _RESILIENCE_STATS["capacity_retries"] += 1
 
     # ---- Wave 1: dispatch every chunk's enumerate + allocate, no syncs ----
-    pend = []
-    for item in items:
-        dev = devices[item.shard]
-        a_ip, a_ix, a_dt = a_ops[item.shard]
-        b_ix, b_vl, b_rm = b_entry.shards[item.shard]
-        rmk = b_rm is not None
-        padded, rows_j = _chunk_rows_padded(item, dev)
-        enum = _get_program(
-            "enumerate", (padded, item.a_cap, kb_cap, gather, dt, rmk),
-            item.a_cap, kb_cap, gather, rmk)
-        keys, vals = enum(a_ip, a_ix, a_dt, rows_j, b_ix, b_vl, b_rm)
-        pend.append((item, padded, keys, vals,
-                     _alloc_counts(keys, padded, item.table_cap,
-                                   item.engine or engine)))
+    with jax.profiler.TraceAnnotation("spgemm.dispatch"):
+        pend = []
+        for item in items:
+            dev = devices[item.shard]
+            a_ip, a_ix, a_dt = a_ops[item.shard]
+            b_ix, b_vl, b_rm = b_entry.shards[item.shard]
+            rmk = b_rm is not None
+            padded, rows_j = _chunk_rows_padded(item, dev)
+            enum = _get_program(
+                "enumerate", (padded, item.a_cap, kb_cap, gather, dt, rmk),
+                item.a_cap, kb_cap, gather, rmk)
+            keys, vals = enum(a_ip, a_ix, a_dt, rows_j, b_ix, b_vl, b_rm)
+            pend.append((item, padded, keys, vals,
+                         _alloc_counts(keys, padded, item.table_cap,
+                                       item.engine or engine)))
 
     # ---- The one coalesced host sync: size every out_cap at once ----
     unique_counts, indptr, nnz, cap = _coalesce_and_size(pend, n)
 
     # ---- Wave 2: accumulate on device-resident keys + device epilogue ----
-    epi = _Epilogue(
-        devices, cap, dtype, dt,
-        seg_caps=_shard_seg_caps(
-            [p[0] for p in pend], len(devices),
-            [int(uc[: len(p[0].rows)].sum()) for p, uc in
-             zip(pend, unique_counts)]))
-    for i, uc in enumerate(unique_counts):
-        item, padded, keys, vals, _ = pend[i]
-        pend[i] = None  # free this chunk's intermediates once consumed
-        eng_name = item.engine or engine
-        out_cap = _out_cap_from_counts(uc, item.table_cap, ncol_cap)
-        ip_cap = keys.shape[1]
-        accum = _get_program(
-            "accumulate",
-            (padded, ip_cap, item.table_cap, out_cap, eng_name, dt),
-            item.table_cap, out_cap, eng_name)
-        cols_r, vals_r, counts_r = accum(keys, vals)
-        # sharded epilogue: starts/outputs stay on the shard device
-        starts_dev = devices[item.shard] if epi.sharded else epi.merge_dev
-        epi.add_chunk(
-            _ChunkRun(item, padded, out_cap, cols_r, vals_r, counts_r),
-            _chunk_starts(indptr, item.rows, padded, starts_dev))
-    idx_buf, dat_buf = epi.finish()
+    with jax.profiler.TraceAnnotation("spgemm.epilogue"):
+        epi = _Epilogue(
+            devices, cap, dtype, dt,
+            seg_caps=_shard_seg_caps(
+                [p[0] for p in pend], len(devices),
+                [int(uc[: len(p[0].rows)].sum()) for p, uc in
+                 zip(pend, unique_counts)]))
+        for i, uc in enumerate(unique_counts):
+            item, padded, keys, vals, _ = pend[i]
+            pend[i] = None  # free this chunk's intermediates once consumed
+            eng_name = item.engine or engine
+            out_cap = _out_cap_from_counts(uc, item.table_cap, ncol_cap)
+            ip_cap = keys.shape[1]
+            accum = _get_program(
+                "accumulate",
+                (padded, ip_cap, item.table_cap, out_cap, eng_name, dt),
+                item.table_cap, out_cap, eng_name)
+            cols_r, vals_r, counts_r = accum(keys, vals)
+            # sharded epilogue: starts/outputs stay on the shard device
+            starts_dev = devices[item.shard] if epi.sharded else epi.merge_dev
+            epi.add_chunk(
+                _ChunkRun(item, padded, out_cap, cols_r, vals_r, counts_r),
+                _chunk_starts(indptr, item.rows, padded, starts_dev))
+        idx_buf, dat_buf = epi.finish()
 
     c = CSR(jnp.asarray(indptr), idx_buf, dat_buf, shape)
     return c, nnz
@@ -2194,79 +2210,85 @@ def _run_planned(items, devices, a_ops, b_ops, plan, n, dtype, dt, kb_cap,
     bounds = [chunk_capacity_bounds(plan, item.rows, ncol) for item in items]
     cap = _int32_nnz_capacity(sum(s for _, s in bounds))
     bkey = () if batch is None else (batch,)
-    runs: List[_ChunkRun] = []
-    for item, (max_u, _) in zip(items, bounds):
-        eng_name = item.engine or engine
-        eng = get_engine(eng_name)
-        dev = devices[item.shard]
-        a_arrs = a_ops[item.shard]
-        b_ix, b_vl, b_rm = b_ops[item.shard]
-        rmk = b_rm is not None
-        padded, rows_j = _chunk_rows_padded(item, dev)
-        out_cap = _planned_out_cap(max_u, item.table_cap, ncol_cap)
-        if faults.trigger("capacity_undersize"):
-            # Chaos hook (docs/resilience.md): shrink this chunk's planned
-            # capacity below any real row's uniqueCount so the device-side
-            # overflow flag and the measured-capacity retry are exercised.
-            out_cap = 1
-        if eng.fused:
-            if batch is None:
-                kernel = _fused_kernel_mode(dt, item.table_cap)
-                prog = _get_program(
-                    "fused",
-                    (padded, item.a_cap, kb_cap, item.table_cap, out_cap,
-                     gather, dt, kernel, rmk),
-                    item.a_cap, kb_cap, gather, item.table_cap, out_cap, kernel,
-                    rmk)
+    with jax.profiler.TraceAnnotation("spgemm.dispatch"):
+        runs: List[_ChunkRun] = []
+        for item, (max_u, _) in zip(items, bounds):
+            eng_name = item.engine or engine
+            eng = get_engine(eng_name)
+            dev = devices[item.shard]
+            a_arrs = a_ops[item.shard]
+            b_ix, b_vl, b_rm = b_ops[item.shard]
+            rmk = b_rm is not None
+            padded, rows_j = _chunk_rows_padded(item, dev)
+            out_cap = _planned_out_cap(max_u, item.table_cap, ncol_cap)
+            if faults.trigger("capacity_undersize"):
+                # Chaos hook (docs/resilience.md): shrink this chunk's
+                # planned capacity below any real row's uniqueCount so the
+                # device-side overflow flag and the measured-capacity retry
+                # are exercised.
+                out_cap = 1
+            if eng.fused:
+                if batch is None:
+                    kernel = _fused_kernel_mode(dt, item.table_cap)
+                    prog = _get_program(
+                        "fused",
+                        (padded, item.a_cap, kb_cap, item.table_cap, out_cap,
+                         gather, dt, kernel, rmk),
+                        item.a_cap, kb_cap, gather, item.table_cap, out_cap,
+                        kernel, rmk)
+                else:
+                    prog = _get_program(
+                        "bfused",
+                        (batch, padded, item.a_cap, kb_cap, item.table_cap,
+                         out_cap, gather, dt, rmk),
+                        item.a_cap, kb_cap, gather, item.table_cap, out_cap,
+                        rmk)
+                cols_r, vals_r, counts_r = prog(*a_arrs, rows_j, b_ix, b_vl,
+                                                b_rm)
             else:
-                prog = _get_program(
-                    "bfused",
-                    (batch, padded, item.a_cap, kb_cap, item.table_cap,
-                     out_cap, gather, dt, rmk),
-                    item.a_cap, kb_cap, gather, item.table_cap, out_cap, rmk)
-            cols_r, vals_r, counts_r = prog(*a_arrs, rows_j, b_ix, b_vl, b_rm)
-        else:
-            enum = _get_program(
-                "enumerate" if batch is None else "benumerate",
-                bkey + (padded, item.a_cap, kb_cap, gather, dt, rmk),
-                item.a_cap, kb_cap, gather, rmk)
-            keys, vals = enum(*a_arrs, rows_j, b_ix, b_vl, b_rm)
-            accum = _get_program(
-                "accumulate" if batch is None else "baccumulate",
-                bkey + (padded, keys.shape[1], item.table_cap, out_cap,
-                        eng_name, dt),
-                item.table_cap, out_cap, eng_name)
-            cols_r, vals_r, counts_r = accum(keys, vals)
-        runs.append(_ChunkRun(item, padded, out_cap, cols_r, vals_r,
-                              counts_r))
+                enum = _get_program(
+                    "enumerate" if batch is None else "benumerate",
+                    bkey + (padded, item.a_cap, kb_cap, gather, dt, rmk),
+                    item.a_cap, kb_cap, gather, rmk)
+                keys, vals = enum(*a_arrs, rows_j, b_ix, b_vl, b_rm)
+                accum = _get_program(
+                    "accumulate" if batch is None else "baccumulate",
+                    bkey + (padded, keys.shape[1], item.table_cap, out_cap,
+                            eng_name, dt),
+                    item.table_cap, out_cap, eng_name)
+                cols_r, vals_r, counts_r = accum(keys, vals)
+            runs.append(_ChunkRun(item, padded, out_cap, cols_r, vals_r,
+                                  counts_r))
 
     # ---- Device-side CSR sizing: indptr/nnz never visit the host ----
-    merge_dev = merge_device(devices)
-    indptr, nnz = _device_indptr(runs, n, merge_dev)
+    with jax.profiler.TraceAnnotation("spgemm.epilogue"):
+        merge_dev = merge_device(devices)
+        indptr, nnz = _device_indptr(runs, n, merge_dev)
 
-    # Device-side capacity-overflow flag: engine counts are TRUE per-row
-    # uniqueCounts (never clipped to out_cap), so ``counts > out_cap``
-    # detects an under-sized chunk whose cols/vals buffers were trimmed.
-    # Computed async here (a handful of scalar reductions, no sync); the
-    # caller decides whether to *read* it — see ``_capacity_overflow``.
-    overflow = None
-    for run in runs:
-        f = replicate_to(
-            jnp.any(run.counts[: len(run.item.rows)] > run.out_cap),
-            merge_dev)
-        overflow = f if overflow is None else jnp.logical_or(overflow, f)
+        # Device-side capacity-overflow flag: engine counts are TRUE
+        # per-row uniqueCounts (never clipped to out_cap), so ``counts >
+        # out_cap`` detects an under-sized chunk whose cols/vals buffers
+        # were trimmed.  Computed async here (a handful of scalar
+        # reductions, no sync); the caller decides whether to *read* it —
+        # see ``_capacity_overflow``.
+        overflow = None
+        for run in runs:
+            f = replicate_to(
+                jnp.any(run.counts[: len(run.item.rows)] > run.out_cap),
+                merge_dev)
+            overflow = f if overflow is None else jnp.logical_or(overflow, f)
 
-    epi = _Epilogue(devices, cap, dtype, dt, batch=batch,
-                    seg_caps=_shard_seg_caps(items, len(devices),
-                                             [s for _, s in bounds]))
-    indptr_by_dev = {merge_dev: indptr}
-    for run in runs:
-        dev = devices[run.item.shard] if epi.sharded else merge_dev
-        if dev not in indptr_by_dev:
-            indptr_by_dev[dev] = replicate_to(indptr, dev)
-        epi.add_chunk(run, _device_chunk_starts(
-            indptr_by_dev[dev], run.item.rows, run.padded, dev))
-    idx_buf, dat_buf = epi.finish()
+        epi = _Epilogue(devices, cap, dtype, dt, batch=batch,
+                        seg_caps=_shard_seg_caps(items, len(devices),
+                                                 [s for _, s in bounds]))
+        indptr_by_dev = {merge_dev: indptr}
+        for run in runs:
+            dev = devices[run.item.shard] if epi.sharded else merge_dev
+            if dev not in indptr_by_dev:
+                indptr_by_dev[dev] = replicate_to(indptr, dev)
+            epi.add_chunk(run, _device_chunk_starts(
+                indptr_by_dev[dev], run.item.rows, run.padded, dev))
+        idx_buf, dat_buf = epi.finish()
     return indptr, idx_buf, dat_buf, nnz, overflow
 
 
@@ -2469,9 +2491,10 @@ def execute_plan_batched(
         mode = "measured"
     else:
         mode = resolve_sizing(sizing, engine, plan, group_engines)
-    gather, kb_cap, ncol_cap, devices, items, footprints = _setup_execution(
-        a, b, plan, engine, gather, row_chunk, mesh,
-        group_engines=group_engines, operands=operands)
+    with jax.profiler.TraceAnnotation("spgemm.setup"):
+        gather, kb_cap, ncol_cap, devices, items, footprints = (
+            _setup_execution(a, b, plan, engine, gather, row_chunk, mesh,
+                             group_engines=group_engines, operands=operands))
     n = a.n_rows
     a_data_batch, batch, a_shards, b_shards = _batched_operands(
         a, b, a_data_batch, b_data_batch, ell_width(kb_cap, gather), devices,
@@ -2493,49 +2516,51 @@ def execute_plan_batched(
         _RESILIENCE_STATS["capacity_retries"] += 1
 
     # ---- Wave 1: every chunk's benumerate + allocate, no syncs ----
-    pend = []
-    for item in items:
-        dev = devices[item.shard]
-        a_ip, a_ix, a_db = a_shards[item.shard]
-        b_ix, b_vb, b_rm = b_shards[item.shard]
-        rmk = b_rm is not None
-        padded, rows_j = _chunk_rows_padded(item, dev)
-        benum = _get_program(
-            "benumerate",
-            (batch, padded, item.a_cap, kb_cap, gather, dt, rmk),
-            item.a_cap, kb_cap, gather, rmk)
-        keys, vals_b = benum(a_ip, a_ix, a_db, rows_j, b_ix, b_vb, b_rm)
-        pend.append((item, padded, keys, vals_b,
-                     _alloc_counts(keys, padded, item.table_cap,
-                                   item.engine or engine)))
+    with jax.profiler.TraceAnnotation("spgemm.dispatch"):
+        pend = []
+        for item in items:
+            dev = devices[item.shard]
+            a_ip, a_ix, a_db = a_shards[item.shard]
+            b_ix, b_vb, b_rm = b_shards[item.shard]
+            rmk = b_rm is not None
+            padded, rows_j = _chunk_rows_padded(item, dev)
+            benum = _get_program(
+                "benumerate",
+                (batch, padded, item.a_cap, kb_cap, gather, dt, rmk),
+                item.a_cap, kb_cap, gather, rmk)
+            keys, vals_b = benum(a_ip, a_ix, a_db, rows_j, b_ix, b_vb, b_rm)
+            pend.append((item, padded, keys, vals_b,
+                         _alloc_counts(keys, padded, item.table_cap,
+                                       item.engine or engine)))
 
     # ---- One coalesced host sync sizes all chunks for the whole batch ----
     unique_counts, indptr, nnz, cap = _coalesce_and_size(pend, n)
 
     # ---- Wave 2: batched accumulate + device epilogue (value scatter
     # broadcast over the batch axis) ----
-    epi = _Epilogue(
-        devices, cap, dtype, dt, batch=batch,
-        seg_caps=_shard_seg_caps(
-            [p[0] for p in pend], len(devices),
-            [int(uc[: len(p[0].rows)].sum()) for p, uc in
-             zip(pend, unique_counts)]))
-    for i, uc in enumerate(unique_counts):
-        item, padded, keys, vals_b, _ = pend[i]
-        pend[i] = None  # free this chunk's intermediates once consumed
-        eng_name = item.engine or engine
-        out_cap = _out_cap_from_counts(uc, item.table_cap, ncol_cap)
-        ip_cap = keys.shape[1]
-        bacc = _get_program(
-            "baccumulate",
-            (batch, padded, ip_cap, item.table_cap, out_cap, eng_name, dt),
-            item.table_cap, out_cap, eng_name)
-        cols_rb, vals_rb, counts_rb = bacc(keys, vals_b)
-        starts_dev = devices[item.shard] if epi.sharded else epi.merge_dev
-        epi.add_chunk(
-            _ChunkRun(item, padded, out_cap, cols_rb, vals_rb, counts_rb),
-            _chunk_starts(indptr, item.rows, padded, starts_dev))
-    idx_buf, dat_buf_b = epi.finish()
+    with jax.profiler.TraceAnnotation("spgemm.epilogue"):
+        epi = _Epilogue(
+            devices, cap, dtype, dt, batch=batch,
+            seg_caps=_shard_seg_caps(
+                [p[0] for p in pend], len(devices),
+                [int(uc[: len(p[0].rows)].sum()) for p, uc in
+                 zip(pend, unique_counts)]))
+        for i, uc in enumerate(unique_counts):
+            item, padded, keys, vals_b, _ = pend[i]
+            pend[i] = None  # free this chunk's intermediates once consumed
+            eng_name = item.engine or engine
+            out_cap = _out_cap_from_counts(uc, item.table_cap, ncol_cap)
+            ip_cap = keys.shape[1]
+            bacc = _get_program(
+                "baccumulate",
+                (batch, padded, ip_cap, item.table_cap, out_cap, eng_name, dt),
+                item.table_cap, out_cap, eng_name)
+            cols_rb, vals_rb, counts_rb = bacc(keys, vals_b)
+            starts_dev = devices[item.shard] if epi.sharded else epi.merge_dev
+            epi.add_chunk(
+                _ChunkRun(item, padded, out_cap, cols_rb, vals_rb, counts_rb),
+                _chunk_starts(indptr, item.rows, padded, starts_dev))
+        idx_buf, dat_buf_b = epi.finish()
 
     return jnp.asarray(indptr), idx_buf, dat_buf_b, nnz
 

@@ -32,6 +32,7 @@ from __future__ import annotations
 import dataclasses
 from typing import Dict, List, Literal, Optional, Sequence, Union
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -68,14 +69,16 @@ class SpGEMMBatchResult:
 def _resolve_plan(a: CSR, b: CSR, plan: PlanLike) -> GroupPlan:
     """Phase 1, amortized: reuse a given plan, consult a PlanCache, or run
     ``group_rows`` (the paper's per-matrix setup)."""
-    if isinstance(plan, PlanCache):
-        return plan.plan_for(a, b)
-    if isinstance(plan, GroupPlan):
-        return plan
-    if plan is not None:
-        raise TypeError(
-            f"plan must be a GroupPlan, PlanCache, or None; got {type(plan)!r}")
-    return group_rows(a, b)
+    with jax.profiler.TraceAnnotation("spgemm.plan"):
+        if isinstance(plan, PlanCache):
+            return plan.plan_for(a, b)
+        if isinstance(plan, GroupPlan):
+            return plan
+        if plan is not None:
+            raise TypeError(
+                "plan must be a GroupPlan, PlanCache, or None; "
+                f"got {type(plan)!r}")
+        return group_rows(a, b)
 
 
 def spgemm(
@@ -147,49 +150,55 @@ def spgemm(
     docs/resilience.md).  Inert when no budget is configured.
     """
     assert a.n_cols == b.n_rows, (a.shape, b.shape)
-    engine = executor.resolve_engine(engine, method)
-    on_budget = executor.resolve_on_budget(on_budget)
-    # ---- Phase 1: row grouping (one host sync, amortized via ``plan``) ----
-    plan = _resolve_plan(a, b, plan)
-    run_plan = plan
-    if schedule == "natural":
-        run_plan = executor.ungrouped_plan(plan)
-    budget = executor.device_budget()
-    if on_budget == "stream" and budget is not None:
-        itemsize = np.dtype(np.asarray(a.data).dtype).itemsize
-        if executor.estimated_device_bytes(plan, itemsize) > budget:
-            return _degrade_to_stream(
-                a, b, plan, run_plan, itemsize, method=method,
-                row_chunk=row_chunk, schedule=schedule, engine=engine,
-                gather=gather, mesh=mesh, pipeline=pipeline, sizing=sizing,
-                autotune=autotune, operands=operands,
-                operand_cache=operand_cache)
-    # ---- Phases 2+3: compiled group pipeline + device-side reassembly ----
-    c, nnz = executor.execute_plan(
-        a, b, run_plan, engine=engine, gather=gather, row_chunk=row_chunk,
-        mesh=mesh, pipeline=pipeline, sizing=sizing, autotune=autotune,
-        operands=operands, operand_cache=operand_cache,
-    )
-    info = spgemm_info(a, b, run_plan, nnz, mesh=mesh)
-    return SpGEMMResult(c=c, plan=run_plan, info=info)
+    with jax.profiler.TraceAnnotation("spgemm"):
+        engine = executor.resolve_engine(engine, method)
+        on_budget = executor.resolve_on_budget(on_budget)
+        # ---- Phase 1: row grouping (one host sync, amortized via ``plan``)
+        plan = _resolve_plan(a, b, plan)
+        run_plan = plan
+        if schedule == "natural":
+            run_plan = executor.ungrouped_plan(plan)
+        budget = executor.device_budget()
+        if on_budget == "stream" and budget is not None:
+            itemsize = np.dtype(np.asarray(a.data).dtype).itemsize
+            if executor.estimated_device_bytes(plan, itemsize) > budget:
+                return _degrade_to_stream(
+                    a, b, plan, run_plan, itemsize, method=method,
+                    row_chunk=row_chunk, schedule=schedule, engine=engine,
+                    gather=gather, mesh=mesh, pipeline=pipeline,
+                    sizing=sizing, autotune=autotune, operands=operands,
+                    operand_cache=operand_cache)
+        # ---- Phases 2+3: compiled group pipeline + device reassembly ----
+        with jax.profiler.TraceAnnotation("spgemm.execute"):
+            c, nnz = executor.execute_plan(
+                a, b, run_plan, engine=engine, gather=gather,
+                row_chunk=row_chunk, mesh=mesh, pipeline=pipeline,
+                sizing=sizing, autotune=autotune, operands=operands,
+                operand_cache=operand_cache,
+            )
+        info = spgemm_info(a, b, run_plan, nnz, mesh=mesh)
+        return SpGEMMResult(c=c, plan=run_plan, info=info)
 
 
 def spgemm_info(a: CSR, b: CSR, plan: GroupPlan, nnz_c: int,
                 mesh=None) -> Dict[str, float]:
-    """Hardware-independent counters used throughout EXPERIMENTS.md."""
-    total_ip = plan.total_ip
-    return {
-        "n_shards": 1 if mesh is None else int(np.prod(
-            np.asarray(mesh.devices).shape)),
-        "nnz_a": int(np.asarray(a.nnz)),
-        "nnz_b": int(np.asarray(b.nnz)),
-        "nnz_c": int(nnz_c),
-        "intermediate_products": int(total_ip),
-        "flops": 2.0 * total_ip,  # paper's FLOP definition (§VI Methodology)
-        "compression_ratio": float(total_ip) / max(nnz_c, 1),
-        "group_sizes": list(plan.group_sizes),
-        "max_ip": plan.max_ip,
-    }
+    """Hardware-independent counters of one product, as ``SpGEMMResult.info``
+    carries them.  ``nnz_c`` is a device scalar when the call sized its
+    output on the device (``sizing="planned"``); reading it here waits for
+    the product."""
+    with jax.profiler.TraceAnnotation("spgemm.info"):
+        total_ip = plan.total_ip
+        nnz_c = int(nnz_c)
+        return {
+            "n_shards": 1 if mesh is None else int(np.prod(
+                np.asarray(mesh.devices).shape)),
+            "nnz_c": nnz_c,
+            "intermediate_products": int(total_ip),
+            "flops": 2.0 * total_ip,  # the paper's FLOP definition (§VI)
+            "compression_ratio": float(total_ip) / max(nnz_c, 1),
+            "group_sizes": list(plan.group_sizes),
+            "max_ip": plan.max_ip,
+        }
 
 
 def _degrade_to_stream(a, b, plan, run_plan, itemsize, *, method, row_chunk,
@@ -302,8 +311,6 @@ def spgemm_streamed(
     info = {
         "n_shards": 1 if mesh is None else int(np.prod(
             np.asarray(mesh.devices).shape)),
-        "nnz_a": int(np.asarray(a.nnz)),
-        "nnz_b": int(np.asarray(b.nnz)),
         "nnz_c": int(nnz),
         "intermediate_products": int(total_ip),
         "flops": 2.0 * total_ip,
