@@ -25,7 +25,8 @@ def aia_ranged_gather(x, idx, r: int = 1, backend: Backend = "auto"):
     return _aia.aia_ranged_gather(x, idx, r, interpret=(be == "interpret"))
 
 
-def gather_rows(x, idx, rows_per_block: int = 8, backend: Backend = "auto"):
+def gather_rows(x, idx, rows_per_block: int | None = None,
+                backend: Backend = "auto"):
     be = resolve_backend(backend)
     if be == "xla":
         return _ref.gather_rows(x, idx)

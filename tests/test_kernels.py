@@ -34,13 +34,42 @@ def test_aia_gather_repeated_and_boundary_indices():
     np.testing.assert_array_equal(np.asarray(got), np.asarray(x)[[0, 31, 31, 0, 15]])
 
 
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_gather_rows_manual_dma(dtype):
+@pytest.mark.parametrize("rows_per_block", [None, 8])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16, jnp.int32])
+@pytest.mark.parametrize("d", [20, 128, 256, 300])  # f32: k = 1, 1, 2, 3
+def test_gather_rows_manual_dma(d, dtype, rows_per_block):
+    """Bit-identical to x[idx]: the chosen rows in flight and 8; an id count
+    off the IDX_BLOCK multiple, repeated ids, ids 0 and n-1."""
+    n = 40
     rng = np.random.default_rng(2)
-    x = jnp.asarray(rng.standard_normal((40, 128)), dtype)
-    idx = jnp.asarray(rng.integers(0, 40, 24), jnp.int32)
-    got = aia_k.gather_rows(x, idx, rows_per_block=8, interpret=True)
-    np.testing.assert_array_equal(np.asarray(got), np.asarray(x)[np.asarray(idx)])
+    x = jnp.asarray(rng.integers(-2**15, 2**15, (n, d)), dtype)
+    idx = np.concatenate([[0, n - 1, n - 1, 0],
+                          rng.integers(0, n, aia_k.IDX_BLOCK + 3)])
+    got = aia_k.gather_rows(x, jnp.asarray(idx, jnp.int32),
+                            rows_per_block=rows_per_block, interpret=True)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(x)[idx])
+
+
+@pytest.mark.parametrize("k,rows", [(1, 1024), (2, 512), (3, 256), (8, 128),
+                                    (64, 16), (128, 8), (1000, 8)])
+def test_rows_per_step_follows_row_width(k, rows):
+    assert aia_k.rows_per_step(k) == rows
+
+
+def test_gather_rows_folded_batch_rows():
+    """The batched executor's folded value rows (batch × width words, k = 16
+    tile rows, 64 rows in flight) come back exactly as the XLA gather's."""
+    from repro.core.executor import _gather_b_aia_batched, _gather_b_xla_batched
+
+    rng = np.random.default_rng(3)
+    nb, width, batch, kb = 48, 128, 16, 100
+    b_idx = jnp.asarray(rng.integers(-1, nb, (nb, width)), jnp.int32)
+    b_val = jnp.asarray(rng.standard_normal((batch, nb, width)), jnp.float32)
+    cols_a = jnp.asarray(rng.integers(0, nb, (24, 7)), jnp.int32)
+    got = _gather_b_aia_batched(b_idx, b_val, cols_a, kb)
+    expect = _gather_b_xla_batched(b_idx, b_val, cols_a, kb)
+    for g, e in zip(got, expect):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(e))
 
 
 # ---------------------------------------------------------------------------

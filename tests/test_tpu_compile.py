@@ -22,6 +22,7 @@ from repro.kernels import aia_gather, hash_accum
 
 ROW_CHUNK = 4096  # the executor's default row_chunk
 SMOKE_MATRICES = {"Economics": 206_000, "p2p-Gnutella04": 10_876}
+GCN_NODES, GCN_ENTRIES = 169_343, 2_325_906  # ogbn-arxiv; entries of A_hat
 
 
 @pytest.fixture(scope="module")
@@ -77,6 +78,19 @@ def test_gather_rows_compiles_at_smoke_widths(one_chip, plans, name):
                              sharding=one_chip)
     idx = jax.ShapeDtypeStruct((ROW_CHUNK * a_cap,), jnp.int32,
                                sharding=one_chip)
+    text = _compile(lambda x, i: aia_gather.gather_rows_any(
+        x, i, interpret=False), x, idx)
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("width", [128, 256])
+def test_gather_rows_compiles_at_gcn_widths(one_chip, width):
+    """The GCN aggregation's gathers at ogbn-arxiv's size: the 128 input
+    features and the 256-wide hidden layers (float32), one id per stored
+    entry of A_hat, at the rows in flight chosen for each width."""
+    x = jax.ShapeDtypeStruct((GCN_NODES, width), jnp.float32,
+                             sharding=one_chip)
+    idx = jax.ShapeDtypeStruct((GCN_ENTRIES,), jnp.int32, sharding=one_chip)
     text = _compile(lambda x, i: aia_gather.gather_rows_any(
         x, i, interpret=False), x, idx)
     assert "tpu_custom_call" in text
