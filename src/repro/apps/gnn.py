@@ -59,7 +59,22 @@ def normalize_adjacency(a: CSR) -> CSR:
     return csr_scale_columns(csr_scale_rows(a, dinv), dinv)
 
 
+def mean_adjacency(a: CSR) -> CSR:
+    """D^{-1} A for GraphSAGE's mean aggregation: each row's entries scaled
+    by one over the row's entry count; no self-loops, and an empty row
+    stays zero."""
+    from repro.sparse.ops import csr_scale_rows
+    deg = np.asarray(a.row_nnz()).astype(np.float32)
+    return csr_scale_rows(a, jnp.asarray(1.0 / np.maximum(deg, 1.0)))
+
+
 def init_gnn(cfg: GNNConfig, key) -> Dict:
+    """Initial parameters, drawn from ``key`` by one rule: per layer, in
+    order, ``key, k1, k2 = split(key, 3)``; the aggregated path's weight
+    ``w{l}`` ~ N(0, 1/d_in) from ``k1``; for GraphSAGE the self path's
+    ``w_self{l}`` ~ N(0, 1/d_in) from ``k2`` and a zero bias ``b{l}``; for
+    GIN a zero ``eps{l}``.  The benchmark's plain references draw theirs by
+    the same rule."""
     dims = [cfg.d_in] + [cfg.d_hidden] * (cfg.n_layers - 1) + [cfg.n_classes]
     params = {}
     for layer in range(cfg.n_layers):
@@ -71,6 +86,7 @@ def init_gnn(cfg: GNNConfig, key) -> Dict:
             params[f"w_self{layer}"] = (
                 jax.random.normal(k2, (fan_in, dims[layer + 1])) / np.sqrt(fan_in)
             ).astype(jnp.float32)
+            params[f"b{layer}"] = jnp.zeros((dims[layer + 1],), jnp.float32)
         if cfg.arch == "gin":
             params[f"eps{layer}"] = jnp.zeros((), jnp.float32)
     return params
@@ -98,17 +114,24 @@ def gnn_forward(cfg: GNNConfig, params: Dict, a: CSR, x: jax.Array,
             h = agg @ params[f"w{layer}"]
         elif cfg.arch == "gin":
             h = ((1.0 + params[f"eps{layer}"]) * h + agg) @ params[f"w{layer}"]
-        else:  # sage: self + mean-ish neighbor path
-            h = h @ params[f"w_self{layer}"] + agg @ params[f"w{layer}"]
+        else:  # sage: W_l · (aggregated neighbours) + b_l + W_r · self
+            h = agg @ params[f"w{layer}"] + params[f"b{layer}"] + h @ params[f"w_self{layer}"]
         if layer < cfg.n_layers - 1:
             h = jax.nn.relu(h)
     return h  # logits
 
 
 def _loss_fn(cfg, params, a, x, labels, mask, mesh=None):
+    """Mean loss over the masked nodes: softmax cross-entropy for integer
+    class labels (n,); for float labels (n, tasks), binary cross-entropy
+    with logits, the mean over nodes and tasks (multi-label targets)."""
     logits = gnn_forward(cfg, params, a, x, mesh=mesh)
-    logp = jax.nn.log_softmax(logits, axis=-1)
-    nll = -jnp.take_along_axis(logp, labels[:, None], axis=1)[:, 0]
+    if labels.ndim == 2 and jnp.issubdtype(labels.dtype, jnp.floating):
+        bce = jnp.maximum(logits, 0) - logits * labels + jnp.log1p(jnp.exp(-jnp.abs(logits)))
+        nll = jnp.mean(bce, axis=1)
+    else:
+        logp = jax.nn.log_softmax(logits, axis=-1)
+        nll = -jnp.take_along_axis(logp, labels[:, None], axis=1)[:, 0]
     return jnp.sum(nll * mask) / jnp.maximum(jnp.sum(mask), 1.0)
 
 
@@ -195,7 +218,7 @@ def gnn_forward_minibatch(cfg: GNNConfig, params: Dict, adjs: Sequence[CSR],
         elif cfg.arch == "gin":
             h = ((1.0 + params[f"eps{layer}"]) * h_self + agg) @ params[f"w{layer}"]
         else:  # sage
-            h = h_self @ params[f"w_self{layer}"] + agg @ params[f"w{layer}"]
+            h = agg @ params[f"w{layer}"] + params[f"b{layer}"] + h_self @ params[f"w_self{layer}"]
         if layer < n_layers - 1:
             h = jax.nn.relu(h)
     return h  # logits for frontiers[0] (the batch vertices)

@@ -76,9 +76,13 @@ class CSR:
         """Dynamic occupancy (a traced scalar under jit)."""
         return self.indptr[-1]
 
-    def row_ids(self) -> jax.Array:
-        """Row id of every slot (capacity,); padding slots get ``n_rows``."""
-        p = jnp.arange(self.capacity, dtype=jnp.int32)
+    def row_ids(self, start=None, size: int | None = None) -> jax.Array:
+        """Row id of every slot (capacity,), or of the ``size`` slots from
+        slot ``start`` on; padding slots (and slots past the capacity) get
+        ``n_rows``."""
+        p = jnp.arange(self.capacity if size is None else size, dtype=jnp.int32)
+        if start is not None:
+            p = p + start
         rid = jnp.searchsorted(self.indptr, p, side="right").astype(jnp.int32) - 1
         return jnp.where(p < self.nnz, rid, self.n_rows)
 

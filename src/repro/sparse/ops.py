@@ -83,6 +83,33 @@ def csr_spmv(a: CSR, x: jax.Array) -> jax.Array:
     return jnp.zeros(a.n_rows + 1, contrib.dtype).at[rid].add(contrib)[: a.n_rows]
 
 
+# Bytes of padded gathered rows one aggregation chunk may hold: the smallest
+# power of two at which the ogbn-arxiv GCN's widest aggregation (2.38 GB)
+# stays a single chunk.
+SPMM_CHUNK_BYTES = 4 << 30
+
+
+def spmm_chunks(capacity: int, d: int) -> tuple[int, int]:
+    """(chunks, entries per chunk) of ``csr_spmm`` over ``capacity`` stored
+    entries and rows of width ``d``.
+
+    A chunk gathers at most ``SPMM_CHUNK_BYTES`` of rows padded to the
+    128-lane tile a row DMA moves (``padded_width(d)`` 32-bit words each),
+    whatever the gather backend, so the split is the same on every
+    backend.  Chunks are equal, a multiple of the AIA gather's index block
+    where the budget allows; the last one is padded with invalid slots.
+    """
+    from repro.kernels.aia_gather import IDX_BLOCK, padded_width
+
+    per = max(SPMM_CHUNK_BYTES // (padded_width(d) * 4), 1)
+    if capacity <= per:
+        return 1, capacity
+    unit = IDX_BLOCK if per >= IDX_BLOCK else 1
+    units = -(-capacity // unit)
+    n = -(-units // (per // unit))
+    return n, -(-units // n) * unit
+
+
 def csr_spmm(a: CSR, x: jax.Array, gather: str = "xla", mesh=None) -> jax.Array:
     """Y = A @ X for dense X (n_cols, d): the GNN aggregation primitive.
 
@@ -93,18 +120,48 @@ def csr_spmm(a: CSR, x: jax.Array, gather: str = "xla", mesh=None) -> jax.Array:
     on TPU and XLA elsewhere.  ``mesh`` constrains the output to be
     row-sharded over the mesh's first axis so GSPMD partitions the gather +
     segment-sum across devices (jit-safe: constraint only, no placement).
+
+    The stored entries are split by ``spmm_chunks`` so that no pass holds
+    more than ``SPMM_CHUNK_BYTES`` of gathered rows: each chunk is gathered,
+    scaled and segment-summed into the output in turn, in one ``scan``,
+    and the backward scatter-add runs per chunk too.  One chunk is the
+    plain gather → scale → segment-sum.  Every op, forward and transposed,
+    carries the name scope ``csr_spmm``.
     """
     from repro.core.executor import resolve_gather  # lazy: avoids pkg cycle
     gather = resolve_gather(gather)  # validates + honors REPRO_KERNEL_BACKEND
-    valid = a.valid_mask()
-    rows_of_x = _take_rows(x, a.indices, gather)  # (cap, d)
-    contrib = jnp.where(valid[:, None], a.data[:, None] * rows_of_x, 0)
-    rid = a.row_ids()
-    out = jnp.zeros((a.n_rows + 1, x.shape[1]), contrib.dtype).at[rid].add(contrib)
-    out = out[: a.n_rows]
-    if mesh is not None:
-        from repro.launch.sharding import row_sharding
-        out = jax.lax.with_sharding_constraint(out, row_sharding(mesh, out.ndim))
+    with jax.named_scope("csr_spmm"):
+        n_chunks, chunk = spmm_chunks(a.capacity, x.shape[1])
+        if n_chunks == 1:
+            valid = a.valid_mask()
+            rows_of_x = _take_rows(x, a.indices, gather)  # (cap, d)
+            contrib = jnp.where(valid[:, None], a.data[:, None] * rows_of_x, 0)
+            rid = a.row_ids()
+            out = jnp.zeros((a.n_rows + 1, x.shape[1]), contrib.dtype).at[rid].add(contrib)
+        else:
+            pad = n_chunks * chunk - a.capacity
+            xs = (
+                jnp.pad(a.indices, (0, pad)).reshape(n_chunks, chunk),
+                jnp.pad(a.data, (0, pad)).reshape(n_chunks, chunk),
+                jnp.arange(n_chunks, dtype=jnp.int32) * chunk,
+            )
+
+            def step(out, xs):
+                indices, data, start = xs
+                rid = a.row_ids(start, chunk)  # n_rows on padding slots
+                # The mask scales the entries, not the gathered rows: its
+                # (chunk, d) broadcast would be kept for the backward pass.
+                scale = jnp.where(rid < a.n_rows, data, 0)
+                rows_of_x = _take_rows(x, indices, gather)  # (chunk, d)
+                return out.at[rid].add(scale[:, None] * rows_of_x), None
+
+            dtype = jnp.result_type(a.data.dtype, x.dtype)
+            out = jnp.zeros((a.n_rows + 1, x.shape[1]), dtype)
+            out, _ = jax.lax.scan(step, out, xs)
+        out = out[: a.n_rows]
+        if mesh is not None:
+            from repro.launch.sharding import row_sharding
+            out = jax.lax.with_sharding_constraint(out, row_sharding(mesh, out.ndim))
     return out
 
 
